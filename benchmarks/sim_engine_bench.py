@@ -53,25 +53,22 @@ key (admit ticks and scheduler outcome bit-exact with the monolithic
 
 Sweep fabric (DESIGN.md §11): the artifact closes with a
 ``sweep_throughput`` suite — a ragged 4-scenario x 4-seed trial table
-through ``core/sweep_fabric`` in a SUBPROCESS forced to an 8-device
-host runtime (``--xla_force_host_platform_device_count`` must precede
-jax init), timing configs/sec on 1 device (plain vmap) vs all 8
-(``shard_map`` over ``mesh_for_sweep``). ``--check-parity`` requires
-its ``parity`` row (sharded bitwise-equal to single-device), its
-``compile_reuse`` row (a seed-only re-run adds no jit-cache entry —
-the per-call-jit recompile bug stays fixed) and ``scaling_x >= 1``
-(the sharded fabric must not lose to the vmap; sharding wins even on
-one core because each shard's lockstep while_loop only runs to its
-own slowest lane).
+through ``core/sweep_fabric`` in this process, on the devices it has,
+timing configs/sec on 1 device (plain vmap) vs all of them
+(``shard_map`` over ``mesh_for_sweep``). For a multi-device host
+runtime set ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on
+the bench command. ``--check-parity`` requires its ``parity`` row
+(sharded bitwise-equal to single-device), its ``compile_reuse`` row
+(a seed-only re-run adds no jit-cache entry — the per-call-jit
+recompile bug stays fixed) and, with more than one device,
+``scaling_x >= 1`` (the sharded fabric must not lose to the vmap).
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
-import os
 import resource
-import subprocess
 import sys
 import time
 from typing import Dict, List
@@ -405,28 +402,23 @@ def bench_score_backend(n_jobs: int = 192, n_nodes: int = 84,
     return out
 
 
-SWEEP_DEVICES = 8        # forced host device count for the sweep suite
 SWEEP_TRIALS = 16        # 4 scenarios x 4 seeds
 
 
-def _sweep_child(n_devices: int) -> Dict:
-    """Child-process body of :func:`bench_sweep_throughput` — runs
-    under ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the
-    flag must precede jax initialization, hence the subprocess). One
-    ragged 4-scenario x 4-seed trial table through the sweep fabric on
-    1 device (plain vmap) vs all N (``shard_map``), best-of-2 timed
-    runs each, compile excluded: configs/sec per device count, bitwise
-    parity, the >=1x scaling gate, and the compile-reuse lock (a
-    seed-only re-run must not add a jit-cache entry — the old
-    per-call-jit recompile bug)."""
+def bench_sweep_throughput() -> Dict:
+    """Sweep-fabric throughput suite (configs/sec) on the devices this
+    process has. One ragged 4-scenario x 4-seed trial table through
+    the sweep fabric on 1 device (plain vmap) vs all of them
+    (``shard_map``), best-of-2 timed runs each, compile excluded:
+    configs/sec per device count, bitwise parity, and the
+    compile-reuse lock (a seed-only re-run must not add a jit-cache
+    entry — the old per-call-jit recompile bug). With one device the
+    two runs coincide and no scaling ratio is recorded."""
     import jax
 
     from repro.core import sweep_fabric as fabric
 
-    if len(jax.devices()) != n_devices:
-        raise AssertionError(
-            f"sweep child expected {n_devices} devices, found "
-            f"{len(jax.devices())} — XLA_FLAGS not applied?")
+    n_devices = len(jax.devices())
     cfg = api.make_config("fitgpp", n_jobs=256, n_nodes=8, seed=0)
     names = ("te-flood", "long-tail-be", "burst-storm", "diurnal")
     n_seeds = SWEEP_TRIALS // len(names)
@@ -438,9 +430,10 @@ def _sweep_child(n_devices: int) -> Dict:
         "workload": {"scenarios": list(names), "n_seeds": n_seeds,
                      "n_jobs": 256, "n_nodes": 8, "policy": "fitgpp"},
         "n_trials": SWEEP_TRIALS, "n_devices": n_devices,
+        "platform": jax.devices()[0].platform,
     }
     results, cps = {}, {}
-    for d in (1, n_devices):
+    for d in sorted({1, n_devices}):
         # devices=1 resolves to the plain single-device vmap
         # (mesh_for_sweep returns None), devices=N to the shard_map
         # fabric — NOT mesh=None, which means "auto-pick all devices"
@@ -448,7 +441,7 @@ def _sweep_child(n_devices: int) -> Dict:
                                donate=False)              # compile
         if res.n_devices != d:
             raise AssertionError(
-                f"sweep child asked for {d} devices, fabric used "
+                f"sweep asked for {d} devices, fabric used "
                 f"{res.n_devices}")
         best = 1e9
         for _ in range(2):
@@ -468,7 +461,8 @@ def _sweep_child(n_devices: int) -> Dict:
         raise AssertionError(
             f"sweep sharded-vs-single parity violated: {diff}")
     out["parity"] = True
-    out["scaling_x"] = cps[n_devices] / cps[1]
+    if n_devices > 1:
+        out["scaling_x"] = cps[n_devices] / cps[1]
     # compile-reuse lock: fresh seed values, same shapes -> the cached
     # runner must serve the run without a new jit-cache entry
     before = fabric.compile_stats()
@@ -482,32 +476,6 @@ def _sweep_child(n_devices: int) -> Dict:
     out["compile_stats"] = after
     out["max_rss_mb"] = _rss_mb()
     return out
-
-
-def bench_sweep_throughput(n_devices: int = SWEEP_DEVICES) -> Dict:
-    """Sweep-fabric throughput suite (configs/sec): spawns
-    :func:`_sweep_child` in a subprocess with a FORCED ``n_devices``
-    host-device count (``--xla_force_host_platform_device_count`` only
-    takes effect before jax initializes, which has already happened in
-    this process). The child's JSON row is returned verbatim; its
-    in-run assertions (bitwise sharded-vs-single parity, compile
-    reuse) surface here as a raised error with the child's stderr."""
-    src = os.path.dirname(os.path.dirname(os.path.abspath(api.__file__)))
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count="
-                        f"{n_devices}").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.sim_engine_bench",
-         "--sweep-child", str(n_devices)],
-        capture_output=True, text=True, env=env, cwd=root, timeout=1200)
-    if proc.returncode != 0:
-        raise AssertionError(
-            f"sweep_throughput child failed:\n{proc.stderr[-4000:]}")
-    return json.loads(proc.stdout.splitlines()[-1])
 
 
 def _falsy_parity(obj, path: str = "") -> List[str]:
@@ -577,10 +545,11 @@ def check_speed_rows(out: dict) -> List[str]:
                        f"{sp:.2f}x vs reference")
     if "njobs_scaling" not in out:
         bad.append("missing: njobs_scaling")
-    sx = out.get("sweep_throughput", {}).get("scaling_x")
-    if sx is None:
+    sweep_row = out.get("sweep_throughput", {})
+    sx = sweep_row.get("scaling_x")
+    if sx is None and sweep_row.get("n_devices", 1) > 1:
         bad.append("missing: sweep_throughput.scaling_x")
-    elif sx < SPEED_TOL:
+    elif sx is not None and sx < SPEED_TOL:
         bad.append(f"slow: sweep_throughput sharded fabric at "
                    f"{sx:.2f}x vs single-device vmap")
     return bad
@@ -598,8 +567,6 @@ def emit_json(path: str = "BENCH_sim_engine.json") -> dict:
     out["scenario_suite"] = bench_scenario_suite()
     out["njobs_scaling"] = bench_njobs_scaling()
     out["score_backend"] = bench_score_backend()
-    # subprocess (own forced-8-device jax runtime): parent RSS rows
-    # stay unaffected
     out["sweep_throughput"] = bench_sweep_throughput()
     bad = check_parity_rows(out) + check_speed_rows(out)
     if bad:
@@ -791,11 +758,13 @@ def run_all() -> List[tuple]:
 
     sw = bench_sweep_throughput()
     sharded = sw[f"devices_{sw['n_devices']}"]
+    scaling = (f", {sw['scaling_x']:.1f}x vs 1-device vmap"
+               if "scaling_x" in sw else "")
     rows.append((f"sweep_fabric_{sw['n_trials']}trials",
                  sharded["seconds"] * 1e6,
                  f"{sharded['configs_per_sec']:.1f} configs/s on "
-                 f"{sw['n_devices']} forced host devices, "
-                 f"{sw['scaling_x']:.1f}x vs 1-device vmap, parity ok"))
+                 f"{sw['n_devices']} {sw['platform']} devices{scaling}, "
+                 "parity ok"))
     return rows
 
 
@@ -818,14 +787,7 @@ def main(argv=None) -> None:
     ap.add_argument("--profile", metavar="DIR",
                     help="capture a jax.profiler.trace of one jitted "
                          "engine run into DIR and exit")
-    ap.add_argument("--sweep-child", type=int, metavar="N",
-                    help="internal: sweep_throughput child body under "
-                         "a forced N-device host runtime (prints one "
-                         "JSON row)")
     args = ap.parse_args(argv)
-    if args.sweep_child:
-        print(json.dumps(_sweep_child(args.sweep_child)))
-        return
     if args.profile:
         profile(args.profile)
         return
@@ -851,4 +813,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

@@ -115,7 +115,7 @@ class SimConfig:
     time_mode: str = "event"
     # Score-policy backend for the JAX engine: "jnp" runs Eq. 1-4 as
     # plain jnp; "pallas" fuses score + masked argmin on the policy's
-    # registered TPU kernel (fitgpp only; parity-tested, needs static s).
+    # registered TPU kernel (fitgpp only; parity-tested; s may be traced).
     score_backend: str = "jnp"
     # BEYOND-PAPER (the paper's "non-FIFO settings" future work): allow
     # queued BE jobs behind a blocked head to start when they fit

@@ -71,9 +71,10 @@ def eligible_eq2(te_demand: np.ndarray, demand: np.ndarray,
 
 def _jax_size_eq1(demand, node_cap):
     """Eq. 1 in jnp — the one jnp mirror of :func:`size_eq1`, shared by
-    every score policy's ``jax_score`` (single source for the norm)."""
-    import jax.numpy as jnp
-    return jnp.sqrt(jnp.sum((demand / node_cap) ** 2, axis=-1))
+    every score policy's ``jax_score`` and the fused kernel's scores
+    (single source for the norm and its summation order)."""
+    from repro.kernels.schedule_step import eq1_size
+    return eq1_size(demand, node_cap)
 
 
 class Policy:
@@ -207,8 +208,8 @@ class FitGppPolicy(Policy):
         kernel over the (jobs, nodes) tile — score, best-node Eq. 2
         reduction, masked argmin, gang-fit counts and the BE queue
         scan in one invocation (bit-parity-tested vs ``jax_score``;
-        requires static ``s`` — it is baked into the kernel). The
-        victim selection consumes only ``.victim``."""
+        ``s`` may be traced). The victim selection consumes only
+        ``.victim``."""
         assert backend == "pallas", backend
         import jax.numpy as jnp
         from repro.kernels import ops as kops

@@ -564,23 +564,18 @@ def _score_select(st: State, jobs: Jobs, te: jax.Array, pol, node_cap, s,
     return st, jnp.where(mask_any, main, rnd)
 
 
-def _resolve_score_backend(cfg: SimConfig, spec, s) -> str:
-    """Effective score backend: ``cfg.score_backend``. Accelerated
-    backends need a static ``s`` (it is baked into the kernel), so
-    traced-s sweeps — and policies without the backend — fall back to
-    the jnp path silently. Any static Python number counts as static
-    (an int ``s`` must not silently downgrade a requested kernel)."""
+def _resolve_score_backend(cfg: SimConfig, spec) -> str:
+    """Effective score backend: ``cfg.score_backend``, for a static or
+    a traced ``s`` alike (the kernel takes ``s`` as data). The option
+    is name-level only: a policy that registers no such backend runs
+    its jnp path."""
     if os.environ.get("REPRO_SIM_KERNEL") is not None:
         raise RuntimeError(
             "the REPRO_SIM_KERNEL env override was removed; select the "
             "accelerated score path with SimConfig(score_backend='pallas') "
             "(or --score-backend on the scenarios CLI) instead")
     backend = cfg.score_backend
-    static_s = isinstance(s, (int, float)) and not isinstance(s, bool)
-    if backend != "jnp" and (backend not in spec.score_backends
-                             or not static_s):
-        return "jnp"
-    return backend
+    return backend if backend in spec.score_backends else "jnp"
 
 
 def _until_fits_select(st: State, jobs: Jobs, te: jax.Array, rank_val,
@@ -906,7 +901,7 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
     P = cfg.max_preemptions if P is None else P
     s = cfg.s if s is None else s
     pol = spec.make()                  # decision rule (jax declarations)
-    backend = _resolve_score_backend(cfg, spec, s)
+    backend = _resolve_score_backend(cfg, spec)
     tc = _trace_ctx(n_nodes) if trace else None
     if preemptive and spec.jax_kind is None:
         raise NotImplementedError(

@@ -52,7 +52,6 @@ from typing import Dict, List, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.configs.cluster import SimConfig
@@ -284,8 +283,8 @@ def _runner(cfg: SimConfig, time_mode: Optional[str], out: str,
     batched = jax.vmap(one)
     if mesh is not None:
         spec = trial_spec(mesh)
-        batched = shard_map(batched, mesh=mesh, in_specs=(spec,) * 4,
-                            out_specs=spec, check_rep=False)
+        batched = jax.shard_map(batched, mesh=mesh, in_specs=(spec,) * 4,
+                                out_specs=spec, check_vma=False)
     fn = jax.jit(batched, donate_argnums=(0,) if donate else ())
     _RUNNERS[key] = fn
     return fn

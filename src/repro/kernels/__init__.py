@@ -3,7 +3,7 @@
 Each kernel lives in <name>.py (pl.pallas_call + explicit BlockSpec VMEM
 tiling), dispatches through ops.py (jit'd wrappers with padding/backend
 selection) and is validated against its pure-jnp oracle in ref.py
-(interpret mode on this CPU container; native Mosaic on TPU):
+(interpret mode on the CPU backend; native Mosaic on TPU):
 
   flash_attention — blocked causal/SWA/softcap GQA, online softmax
   lru_scan        — diagonal linear recurrence (RG-LRU / diagonal SSM)

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams
 
 DEFAULT_BLOCK_T = 256
 DEFAULT_BLOCK_R = 512
@@ -35,8 +34,7 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, carry, *, block_t: int):
 
     def step(t, h):
         h = a[t] * h + b[t]
-        pl.store(o_ref, (0, pl.ds(t, 1), slice(None)),
-                 h[None].astype(o_ref.dtype))
+        o_ref[0, pl.ds(t, 1), :] = h[None].astype(o_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, block_t, step, carry[0])
@@ -67,7 +65,7 @@ def lru_scan(a: jax.Array, b: jax.Array, h0: jax.Array = None, *,
         out_specs=pl.BlockSpec((1, bt, br), lambda bi, ri, ti: (bi, ti, ri)),
         out_shape=jax.ShapeDtypeStruct((B, L, R), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, br), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

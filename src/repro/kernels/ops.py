@@ -1,15 +1,13 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
 Handles padding to block multiples, dtype plumbing, and backend
-selection: on the CPU container the kernels execute in interpret mode
+selection: on the CPU backend the kernels execute in interpret mode
 (the kernel body runs as traced Python — bit-accurate semantics, no
-Mosaic); on TPU they compile natively. Set REPRO_PALLAS_INTERPRET=0/1 to
-force either way.
+Mosaic); on TPU they always compile natively.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +20,6 @@ from repro.kernels import ssd_chunk as _sc
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "")
     return jax.default_backend() == "cpu"
 
 
@@ -87,7 +82,7 @@ def lru_scan(a, b, h0=None, *, block_t: int = _ls.DEFAULT_BLOCK_T,
     return out[:, :L, :R]
 
 
-@functools.partial(jax.jit, static_argnames=("s", "block_j"))
+@functools.partial(jax.jit, static_argnames=("block_j",))
 def schedule_step(demand, gp, width, queue_key, assign, free,
                   pending_free, cand, under, be_q, te_demand, node_cap,
                   *, s: float = PAPER_S,
@@ -99,14 +94,15 @@ def schedule_step(demand, gp, width, queue_key, assign, free,
 
     ``demand`` (J, 3); ``assign`` (J, M); ``free``/``pending_free``
     (M, 3); ``gp``/``queue_key`` (J,) f32; ``width`` (J,) i32;
-    ``cand``/``under``/``be_q`` (J,) bool. Pads J to the block
-    multiple (padded rows never fit, never selected). Returns a
+    ``cand``/``under``/``be_q`` (J,) bool; ``s`` may be traced. Pads
+    J to the block multiple (padded rows never fit, never selected).
+    Returns a
     ``SchedulePass``; see kernels/schedule_step for the field
     contract."""
     J = demand.shape[0]
     M = free.shape[0]
-    sz = jnp.sqrt(jnp.sum(jnp.square(
-        demand.astype(jnp.float32) / node_cap.astype(jnp.float32)), -1))
+    sz = _ss.eq1_size(demand.astype(jnp.float32),
+                      node_cap.astype(jnp.float32))
     max_sz = jnp.maximum(jnp.max(jnp.where(cand, sz, 0.0)), 1e-12)
     max_gp = jnp.maximum(
         jnp.max(jnp.where(cand, gp.astype(jnp.float32), 0.0)), 1e-12)

@@ -31,9 +31,9 @@ invocation that returns everything a pass consumes:
 
 Three interchangeable backends share this contract bit-for-bit:
 :func:`schedule_step_jnp` (portable jnp twin — the engine's default),
-:func:`schedule_step_pallas` (TPU Pallas, jobs on the vector lanes,
-two grid phases: reduce then finalize), and the numpy oracle
-``kernels.ref.schedule_step_ref``.
+:func:`schedule_step_pallas` (TPU Pallas over (nodes, jobs) tiles,
+jobs on the vector lanes, two grid phases: reduce then finalize), and
+the jnp oracle ``kernels.ref.schedule_step_ref``.
 """
 from __future__ import annotations
 
@@ -46,7 +46,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.engine.placement import FIT_EPS
-from repro.kernels.pltpu_compat import CompilerParams
 
 DEFAULT_BLOCK_J = 512
 _INF = jnp.inf
@@ -64,11 +63,25 @@ class SchedulePass(NamedTuple):
     nskip: jax.Array        # ()    i32
 
 
+def eq1_size(demand, node_cap):
+    """Eq. 1 size in jnp: the norm of the capacity-normalized demand,
+    summed in the fixed order (cpu, mem, gpu) so that XLA on any
+    backend, the Pallas path and numpy round it alike."""
+    q = jnp.square(demand / node_cap)
+    return jnp.sqrt(q[..., 0] + q[..., 1] + q[..., 2])
+
+
+def eq3_scores(demand, gp, node_cap, max_sz, max_gp, s):
+    """Eq. 3 score over the whole job axis (masking happens at the
+    argmin); shared by both backends so their scores are bitwise equal."""
+    return eq1_size(demand, node_cap) / max_sz + s * (gp / max_gp)
+
+
 def schedule_step_jnp(demand, gp, width, queue_key, assign, free,
                       pending_free, cand, under, be_q, te_demand,
                       node_cap, max_sz, max_gp, s) -> SchedulePass:
     """Portable jnp twin — the op-order reference for both the Pallas
-    kernel (interpret-mode bit-parity) and the engine's default path.
+    kernel (bit-parity on CPU and TPU) and the engine's default path.
 
     demand (J,3) f32; gp/queue_key (J,) f32; width (J,) i32;
     assign (J,M) bool; free/pending_free (M,3) f32; cand/under/be_q
@@ -77,9 +90,7 @@ def schedule_step_jnp(demand, gp, width, queue_key, assign, free,
     """
     demand = demand.astype(jnp.float32)
     free = free.astype(jnp.float32)
-    # Eq. 3 score over the whole job axis (masking happens at argmin)
-    size = jnp.sqrt(jnp.sum(jnp.square(demand / node_cap[None, :]), axis=1))
-    scores = size / max_sz + s * (gp / max_gp)
+    scores = eq3_scores(demand, gp, node_cap, max_sz, max_gp, s)
     # per-(job, node) fit tiles, now and promised
     fits_b = jnp.all(free[None, :, :] >= demand[:, None, :] - FIT_EPS,
                      axis=2)                                   # (J, M)
@@ -108,104 +119,88 @@ def schedule_step_jnp(demand, gp, width, queue_key, assign, free,
                         fit_pend, victim, be_head, be_pick, nskip)
 
 
-def _kernel(scal_ref, dem_ref, gp_ref, wid_ref, key_ref, asg_ref,
-            free_ref, pend_ref, cand_ref, under_ref, beq_ref,
-            score_ref, fits_ref, fnow_ref, fpend_ref, out_ref,
-            red, *, block_j: int):
-    """Two grid phases over the job blocks: phase 0 computes every
-    blockwise output and accumulates the four global reductions
-    (victim argmin, BE head, BE pick) into the ``red`` scratch; phase
-    1 re-reads the fit tiles to count the skips ahead of the (now
-    known) pick key and finalizes the scalar outputs."""
+def _fit_tile(node_ref, dem):
+    """(M, bj) bool: node m covers job j's per-node demand on all three
+    resources. ``node_ref`` is (3, M, 1), ``dem`` (3, bj)."""
+    fits = node_ref[0] >= dem[0:1, :] - FIT_EPS
+    for c in (1, 2):
+        fits = fits & (node_ref[c] >= dem[c:c + 1, :] - FIT_EPS)
+    return fits
+
+
+def _fold_argmin(red, i: int, val, base):
+    """Fold this block's first-minimum of ``val`` (1, bj) into the
+    running (value, index) pair at ``red[i]``, ``red[i + 1]``; a strict
+    ``<`` keeps the earliest block on ties, as ``jnp.argmin`` does."""
+    lmin = jnp.min(val)
+    lane = jax.lax.broadcasted_iota(jnp.int32, val.shape, 1)
+    larg = jnp.min(jnp.where(val == lmin, lane.astype(jnp.float32), _INF))
+    better = lmin < red[i]
+    red[i + 1] = jnp.where(better, larg + base, red[i + 1])
+    red[i] = jnp.where(better, lmin, red[i])
+
+
+def _kernel(te_ref, dem_ref, score_ref, wid_ref, key_ref, asg_ref,
+            free_ref, prom_ref, cand_ref, under_ref, beq_ref,
+            fits_ref, fnow_ref, fpend_ref, out_ref, red, *,
+            block_j: int, n_blocks: int):
+    """Two grid phases over the job blocks, jobs on the lanes and nodes
+    on the sublanes of every (M, bj) tile. Phase 0 writes every
+    blockwise output and folds the three global argmins (victim, BE
+    head, BE pick) into the SMEM scratch ``red``; phase 1 recomputes
+    the fit counts to count the skips ahead of the (now known) pick
+    key, and its last step writes the four scalars to SMEM."""
     ph = pl.program_id(0)
     ji = pl.program_id(1)
-    nj = pl.num_programs(1)
 
-    s_par = scal_ref[0]                 # te_c te_r te_g cap_c cap_r cap_g
-    te = s_par[0:3]                     # max_sz max_gp
-    cap = s_par[3:6]
-    max_sz, max_gp = s_par[6], s_par[7]
-    s_w = scal_ref[1, 0]
-
-    dem = dem_ref[0].astype(jnp.float32)      # (bj, 3)
-    gp = gp_ref[0].astype(jnp.float32)        # (bj,)
-    wid = wid_ref[0].astype(jnp.float32)      # (bj,)
-    key = key_ref[0].astype(jnp.float32)      # (bj,)
-    asg = asg_ref[0] > 0                      # (bj, M)
-    free = free_ref[0].astype(jnp.float32)    # (M, 3)
-    pend = pend_ref[0].astype(jnp.float32)    # (M, 3)
-    cand = cand_ref[0] > 0                    # (bj,)
-    under = under_ref[0] > 0
-    be_q = beq_ref[0] > 0
-
-    fits_b = jnp.all(free[None, :, :] >= dem[:, None, :] - FIT_EPS,
-                     axis=2)                                  # (bj, M)
-    fit_now = jnp.sum(fits_b, axis=1)
-    ok = be_q & (fit_now >= wid)
+    dem = dem_ref[...]                        # (3, bj)
+    fit_now = jnp.sum(_fit_tile(free_ref, dem).astype(jnp.float32),
+                      axis=0, keepdims=True)  # (1, bj)
+    key = key_ref[...]
+    be_q = beq_ref[...] > 0
+    ok = be_q & (fit_now >= wid_ref[...])
 
     @pl.when(ph == 0)
     def _reduce():
         @pl.when(ji == 0)
         def _init():
-            red[0, 0] = _INF            # victim best score
-            red[0, 1] = -1.0            # victim index
-            red[0, 2] = _INF            # head best key
-            red[0, 3] = -1.0            # head index
-            red[0, 4] = _INF            # pick best key
-            red[0, 5] = -1.0            # pick index
-            red[0, 6] = 0.0             # nskip accumulator
+            for i in range(0, 6, 2):
+                red[i] = _INF           # victim score / head key / pick key
+                red[i + 1] = -1.0       # ... and its job index
+            red[6] = 0.0                # nskip accumulator
 
-        size = jnp.sqrt(jnp.sum(jnp.square(dem / cap[None, :]), axis=1))
-        score = size / max_sz + s_w * (gp / max_gp)
-        slack = jnp.min(free[None, :, :] + dem[:, None, :]
-                        - te[None, None, :], axis=2)          # (bj, M)
-        best = jnp.max(jnp.where(asg, slack, -_INF), axis=1)
-        allowed = cand & under & (best >= -FIT_EPS)
+        slack = (free_ref[0] + dem[0:1, :]) - te_ref[0, 0]
+        for c in (1, 2):
+            slack = jnp.minimum(
+                slack, (free_ref[c] + dem[c:c + 1, :]) - te_ref[0, c])
+        best = jnp.max(jnp.where(asg_ref[...] > 0, slack, -_INF),
+                       axis=0, keepdims=True)             # (1, bj)
+        allowed = ((cand_ref[...] > 0) & (under_ref[...] > 0)
+                   & (best >= -FIT_EPS))
 
-        score_ref[0] = score.astype(score_ref.dtype)
-        fits_ref[0] = fits_b.astype(fits_ref.dtype)
-        fnow_ref[0] = fit_now.astype(fnow_ref.dtype)
-        fpend_ref[0] = jnp.sum(jnp.all(
-            (free + pend)[None, :, :] >= dem[:, None, :] - FIT_EPS,
-            axis=2), axis=1).astype(fpend_ref.dtype)
+        fits_ref[...] = _fit_tile(free_ref, dem).astype(fits_ref.dtype)
+        fnow_ref[...] = fit_now.astype(fnow_ref.dtype)
+        fpend_ref[...] = jnp.sum(
+            _fit_tile(prom_ref, dem).astype(jnp.float32), axis=0,
+            keepdims=True).astype(fpend_ref.dtype)
 
-        base = jnp.float32(ji * block_j)
-        val = jnp.where(allowed, score, _INF)
-        lmin = jnp.min(val)
-        larg = jnp.argmin(val).astype(jnp.float32) + base
-        better = lmin < red[0, 0]
-        red[0, 0] = jnp.where(better, lmin, red[0, 0])
-        red[0, 1] = jnp.where(better, larg, red[0, 1])
-
-        kq = jnp.where(be_q, key, _INF)
-        lmin = jnp.min(kq)
-        larg = jnp.argmin(kq).astype(jnp.float32) + base
-        better = lmin < red[0, 2]
-        red[0, 2] = jnp.where(better, lmin, red[0, 2])
-        red[0, 3] = jnp.where(better, larg, red[0, 3])
-
-        ko = jnp.where(ok, key, _INF)
-        lmin = jnp.min(ko)
-        larg = jnp.argmin(ko).astype(jnp.float32) + base
-        better = lmin < red[0, 4]
-        red[0, 4] = jnp.where(better, lmin, red[0, 4])
-        red[0, 5] = jnp.where(better, larg, red[0, 5])
+        base = (ji * block_j).astype(jnp.float32)
+        _fold_argmin(red, 0, jnp.where(allowed, score_ref[...], _INF), base)
+        _fold_argmin(red, 2, jnp.where(be_q, key, _INF), base)
+        _fold_argmin(red, 4, jnp.where(ok, key, _INF), base)
 
     @pl.when(ph == 1)
     def _finalize():
-        pick_key = red[0, 4]
-        red[0, 6] += jnp.sum((be_q & ~ok & (key < pick_key))
-                             .astype(jnp.float32))
+        pick_key = red[4]
+        red[6] += jnp.sum((be_q & ~ok & (key < pick_key))
+                          .astype(jnp.float32))
 
-        @pl.when(ji == nj - 1)
+        @pl.when(ji == n_blocks - 1)
         def _emit():
-            out_ref[0, 0] = jnp.where(red[0, 0] < _INF, red[0, 1], -1.0) \
-                .astype(jnp.int32)
-            out_ref[0, 1] = jnp.where(red[0, 2] < _INF, red[0, 3], -1.0) \
-                .astype(jnp.int32)
-            out_ref[0, 2] = jnp.where(red[0, 4] < _INF, red[0, 5], -1.0) \
-                .astype(jnp.int32)
-            out_ref[0, 3] = red[0, 6].astype(jnp.int32)
+            for k in range(3):
+                out_ref[0, k] = jnp.where(red[2 * k] < _INF, red[2 * k + 1],
+                                       -1.0).astype(jnp.int32)
+            out_ref[0, 3] = red[6].astype(jnp.int32)
 
 
 def schedule_step_pallas(demand, gp, width, queue_key, assign, free,
@@ -214,59 +209,66 @@ def schedule_step_pallas(demand, gp, width, queue_key, assign, free,
                          block_j: int = DEFAULT_BLOCK_J,
                          interpret: bool = False) -> SchedulePass:
     """Pallas TPU backend of the fused pass (same contract as
-    :func:`schedule_step_jnp`; jobs on the vector lanes, grid =
-    (2 phases, J/block_j job blocks))."""
+    :func:`schedule_step_jnp`; grid = (2 phases, J/block_j job blocks)).
+
+    The kernel works on (nodes, jobs) tiles, so the (J, M) ``assign``
+    goes in transposed and ``fits`` comes out transposed; the Eq. 3
+    scores are computed in XLA by the twin's own :func:`eq3_scores`.
+    Scalars live in SMEM: the TE demand, the reduction state and the
+    four scalar outputs."""
     J = demand.shape[0]
     M = free.shape[0]
     bj = min(block_j, J)
     assert J % bj == 0, (J, bj)
-    scalars = jnp.stack([
-        jnp.concatenate([te_demand.astype(jnp.float32),
-                         node_cap.astype(jnp.float32),
-                         jnp.stack([jnp.asarray(max_sz, jnp.float32),
-                                    jnp.asarray(max_gp, jnp.float32)])]),
-        jnp.full((8,), s, jnp.float32),
-    ])                                  # (2, 8)
+    n_blocks = J // bj
+    f32 = jnp.float32
+    demand = demand.astype(f32)
+    free = free.astype(f32)
+    scores = eq3_scores(demand, gp.astype(f32), node_cap.astype(f32),
+                        max_sz, max_gp, s)
+
+    def row(x):                         # (J,) -> (1, J), jobs on lanes
+        return x.astype(f32)[None, :]
+
+    def node_cols(x):                   # (M, 3) -> (3, M, 1)
+        return x.T[:, :, None]
+
+    def phase0(ph, ji):
+        # blocks only phase 0 touches stay on the last block through
+        # phase 1: nothing is fetched again, and no output buffer is
+        # written back over what phase 0 stored
+        return 0, ji + ph * (n_blocks - 1 - ji)
 
     job_vec = pl.BlockSpec((1, bj), lambda ph, ji: (0, ji))
-    node_mat = pl.BlockSpec((1, M, 3), lambda ph, ji: (0, 0, 0))
-    scores, fits, fit_now, fit_pend, out = pl.pallas_call(
-        functools.partial(_kernel, block_j=bj),
-        grid=(2, J // bj),
+    job_vec0 = pl.BlockSpec((1, bj), phase0)
+    tile0 = pl.BlockSpec((M, bj), phase0)
+    nodes = pl.BlockSpec((3, M, 1), lambda ph, ji: (0, 0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    fits, fit_now, fit_pend, out = pl.pallas_call(
+        functools.partial(_kernel, block_j=bj, n_blocks=n_blocks),
+        grid=(2, n_blocks),
         in_specs=[
-            pl.BlockSpec((2, 8), lambda ph, ji: (0, 0)),
-            pl.BlockSpec((1, bj, 3), lambda ph, ji: (0, ji, 0)),
-            job_vec, job_vec, job_vec,
-            pl.BlockSpec((1, bj, M), lambda ph, ji: (0, ji, 0)),
-            node_mat, node_mat,
-            job_vec, job_vec, job_vec,
+            smem,                                          # te (1, 3)
+            pl.BlockSpec((3, bj), lambda ph, ji: (0, ji)),  # demand^T
+            job_vec0, job_vec, job_vec,                    # score wid key
+            tile0,                                         # assign^T
+            nodes, nodes,                                  # free, promised
+            job_vec0, job_vec0, job_vec,                   # cand under be_q
         ],
-        out_specs=[
-            job_vec,
-            pl.BlockSpec((1, bj, M), lambda ph, ji: (0, ji, 0)),
-            job_vec, job_vec,
-            pl.BlockSpec((1, 8), lambda ph, ji: (0, 0)),
-        ],
+        out_specs=[tile0, job_vec0, job_vec0, smem],
         out_shape=[
-            jax.ShapeDtypeStruct((1, J), jnp.float32),
-            jax.ShapeDtypeStruct((1, J, M), jnp.int32),
+            jax.ShapeDtypeStruct((M, J), jnp.int32),
             jax.ShapeDtypeStruct((1, J), jnp.int32),
             jax.ShapeDtypeStruct((1, J), jnp.int32),
-            jax.ShapeDtypeStruct((1, 8), jnp.int32),
+            jax.ShapeDtypeStruct((1, 4), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, 8), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.SMEM((8,), f32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(scalars, demand[None].astype(jnp.float32),
-      gp[None].astype(jnp.float32),
-      width[None].astype(jnp.float32),
-      queue_key[None].astype(jnp.float32),
-      assign[None].astype(jnp.float32),
-      free[None].astype(jnp.float32),
-      pending_free[None].astype(jnp.float32),
-      cand[None].astype(jnp.float32),
-      under[None].astype(jnp.float32),
-      be_q[None].astype(jnp.float32))
-    return SchedulePass(scores[0], fits[0], fit_now[0], fit_pend[0],
+    )(te_demand.astype(f32)[None, :], demand.T, row(scores), row(width),
+      row(queue_key), assign.T.astype(f32), node_cols(free),
+      node_cols(free + pending_free.astype(f32)), row(cand), row(under),
+      row(be_q))
+    return SchedulePass(scores, fits.T, fit_now[0], fit_pend[0],
                         out[0, 0], out[0, 1], out[0, 2], out[0, 3])

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams
 
 _NEG_INF = -1e30
 
@@ -79,7 +78,7 @@ def ssd_chunk(xdt: jax.Array, loga: jax.Array, Bm: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, Q, P), lambda z, ci: (z, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, L, P), xdt.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xz, lz, bz, cz)

@@ -7,7 +7,6 @@ initialization and only then calls ``make_production_mesh``.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Optional
 
 import jax
@@ -33,30 +32,23 @@ def mesh_for_sweep(n_trials: Optional[int] = None,
                    devices: Optional[int] = None,
                    axis: str = "data"):
     """1-D trial mesh for the sweep fabric (DESIGN.md §11), or ``None``
-    for the single-device fallback.
+    for the single-device vmap.
 
     Picks ``min(devices or all-local-devices, n_trials)`` devices on a
-    1-D ``(axis,)`` mesh. The fallback to single-device (fewer devices
-    present than requested, or only one available when more were asked
-    for) is LOUD — a ``UserWarning`` — never silent, so a sweep that
-    was meant to shard can't quietly run 8x slower. ``None`` (rather
-    than a 1-device mesh) tells ``sweep_fabric.run_table`` to skip
-    ``shard_map`` entirely; results are bit-identical either way."""
+    1-D ``(axis,)`` mesh. Asking for more devices than the runtime has
+    raises: a sweep that was meant to shard never quietly runs on
+    fewer. ``None`` (rather than a 1-device mesh) tells
+    ``sweep_fabric.run_table`` to skip ``shard_map`` entirely; results
+    are bit-identical either way."""
     avail = len(jax.devices())
     want = avail if devices is None else int(devices)
     if want > avail:
-        warnings.warn(
+        raise ValueError(
             f"mesh_for_sweep: {want} devices requested but only {avail} "
-            f"present; falling back to {avail}", stacklevel=2)
-        want = avail
+            f"present ({jax.devices()[0].platform})")
     if n_trials is not None:
         want = min(want, max(int(n_trials), 1))
     if want <= 1:
-        if devices is not None and devices > 1:
-            warnings.warn(
-                "mesh_for_sweep: falling back to SINGLE-DEVICE vmap "
-                f"(requested {devices} devices, usable {want})",
-                stacklevel=2)
         return None
     return jax.make_mesh((want,), (axis,))
 

@@ -245,8 +245,8 @@ def main(argv=None) -> None:
                         "sweep (per-lane event jumps)")
     p.add_argument("--devices", type=int, default=None,
                    help="cap the sweep-fabric trial mesh at N devices "
-                        "(default: every local device; loud fallback "
-                        "when fewer are present)")
+                        "(default: every local device; more than are "
+                        "present is an error)")
     p.add_argument("--mesh", default="auto", choices=("auto", "off"),
                    help="'off' forces the single-device vmap "
                         "(bit-identical to the sharded run)")
@@ -257,4 +257,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

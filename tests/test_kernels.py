@@ -355,16 +355,27 @@ class TestFitgppScoreBackend:
                                       np.asarray(st_jnp.last_vacate))
 
     def test_traced_s_falls_back_to_jnp(self):
-        """Vmapped s-sweeps cannot bake s into the kernel: the resolver
-        silently falls back to the jnp path instead of tracing-erroring."""
-        from repro.configs.cluster import SimConfig
-        from repro.core import policy_registry, sim_jax
-        cfg = SimConfig(policy="fitgpp", score_backend="pallas")
-        spec = policy_registry.get_policy("fitgpp")
-        assert sim_jax._resolve_score_backend(cfg, spec, 4.0) == "pallas"
-        assert sim_jax._resolve_score_backend(cfg, spec, 4) == "pallas"
+        """A traced ``s`` (vmapped s-sweeps) no longer falls back to
+        jnp: the kernel takes ``s`` as data, the resolver keeps the
+        requested backend, and a jitted run with a traced ``s`` on the
+        kernel equals the jnp path bitwise. A policy that registers no
+        such backend still runs its jnp path."""
+        import dataclasses
+        from repro.configs.cluster import SimConfig, WorkloadSpec
+        from repro.core import policy_registry, sim_jax, workload
+        cfg = SimConfig(workload=WorkloadSpec(n_jobs=96), policy="fitgpp",
+                        seed=3)
+        pal = dataclasses.replace(cfg, score_backend="pallas")
         assert sim_jax._resolve_score_backend(
-            cfg, spec, jnp.asarray(4.0)) == "jnp"
+            pal, policy_registry.get_policy("fitgpp")) == "pallas"
+        assert sim_jax._resolve_score_backend(
+            SimConfig(policy="lrtp", score_backend="pallas"),
+            policy_registry.get_policy("lrtp")) == "jnp"
+        jobs = sim_jax.jobs_from_jobset(workload.generate(cfg))
+        run = jax.jit(lambda c, s: sim_jax.run(c, jobs, seed=3, s=s),
+                      static_argnums=0)
+        s = jnp.asarray(2.0, jnp.float32)
+        assert sim_jax.state_diff_fields(run(cfg, s), run(pal, s)) == []
 
 
 @needs_dev_deps

@@ -16,7 +16,7 @@ initializes. Covered here:
   accounting exactly; resched intervals excluded — the fabric pools
   the JAX State's last signal→resume gap per job, the reference
   engine every gap);
-- ``mesh_for_sweep`` fallback behavior is loud, never silent;
+- ``mesh_for_sweep`` raises when asked for more devices than exist;
 - the compile-once contract: seed/s/P re-runs add no compilations
   (the old per-call ``run_sweep`` recompile bug);
 - donation is alias-safe and a no-op on CPU.
@@ -25,7 +25,6 @@ import dataclasses
 import os
 import subprocess
 import sys
-import warnings
 
 import jax
 import numpy as np
@@ -36,10 +35,6 @@ from repro.configs.cluster import ClusterSpec, SimConfig, WorkloadSpec
 from repro.core import metrics, sim_jax, simulator, sweep_fabric
 from repro.core import sweep
 from repro.launch.mesh import mesh_for_sweep
-
-pytestmark = pytest.mark.filterwarnings(
-    "ignore:.*shard_map.*:DeprecationWarning")
-
 
 def _cfg(policy="fitgpp", n_jobs=64, nodes=8, **kw):
     return SimConfig(cluster=ClusterSpec(n_nodes=nodes),
@@ -191,14 +186,17 @@ class TestMeshForSweep:
         assert mesh_for_sweep(8) is None
 
     def test_over_request_warns(self):
+        """Asking for more devices than exist raises; there is no
+        quiet fallback to fewer."""
         avail = len(jax.devices())
-        with pytest.warns(UserWarning, match="requested"):
+        with pytest.raises(ValueError, match="requested"):
             mesh_for_sweep(64, devices=avail + 7)
+        with pytest.raises(ValueError, match="requested"):
+            sweep_fabric.run_table(*_table(n_seeds=2), devices=avail + 1,
+                                   donate=False)
 
     def test_capped_by_n_trials(self):
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            assert mesh_for_sweep(1, devices=8) is None
+        assert mesh_for_sweep(1, devices=len(jax.devices())) is None
 
     def test_run_table_auto_mesh_single_device(self):
         if len(jax.devices()) != 1:
