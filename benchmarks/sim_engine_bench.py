@@ -33,9 +33,7 @@ of the per-event emission, DESIGN.md §8 — the untraced
 event-compressed step is itself only microseconds long).
 ``--smoke`` round-trips a tiny trace through both export
 formats (``--trace-out`` saves the Perfetto JSON artifact) and
-re-verifies the streamed-vs-monolithic bit-parity window;
-``--profile DIR`` captures a ``jax.profiler.trace`` of one jitted
-engine run.
+re-verifies the streamed-vs-monolithic bit-parity window.
 
 Streaming (DESIGN.md §10): the JSON artifact opens with a ``stream``
 suite — a >=10^5-job synthetic trace through the bounded-memory
@@ -657,26 +655,6 @@ def smoke(n_jobs: int = 64, seed: int = 0,
           + (f", trace -> {trace_out}" if trace_out else ""))
 
 
-def profile(outdir: str, n_jobs: int = 1024, n_nodes: int = 8,
-            policy: str = "fitgpp", seed: int = 0) -> None:
-    """Capture a ``jax.profiler.trace`` of one jitted engine run
-    (compile excluded) into ``outdir`` — open with TensorBoard or
-    ui.perfetto.dev. This profiles the ENGINE's XLA execution; the
-    scheduler-event traces (``--smoke --trace-out`` / the scenarios
-    CLI ``--trace``) profile the simulated cluster."""
-    import jax
-    cfg = api.make_config(policy, n_jobs=n_jobs, n_nodes=n_nodes,
-                          seed=seed)
-    js = scenarios.build("paper-synthetic", cfg)
-    jobs = sim_jax.jobs_from_jobset(js)
-    st = sim_jax.run_jit(cfg, jobs, seed)       # compile
-    st.t.block_until_ready()
-    with jax.profiler.trace(outdir):
-        st = sim_jax.run_jit(cfg, jobs, seed)
-        st.t.block_until_ready()
-    print(f"profiled {n_jobs}-job run -> {outdir}")
-
-
 def run_all() -> List[tuple]:
     rows = []
     n = 2048
@@ -784,13 +762,7 @@ def main(argv=None) -> None:
     ap.add_argument("--trace-out", metavar="PATH",
                     help="with --smoke: write the smoke run's Perfetto "
                          "trace to PATH (CI artifact)")
-    ap.add_argument("--profile", metavar="DIR",
-                    help="capture a jax.profiler.trace of one jitted "
-                         "engine run into DIR and exit")
     args = ap.parse_args(argv)
-    if args.profile:
-        profile(args.profile)
-        return
     if args.check_parity:
         with open(args.check_parity) as f:
             data = json.load(f)

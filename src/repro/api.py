@@ -36,6 +36,9 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
+import jax
+from jax.profiler import TraceAnnotation
+
 from repro import scenarios
 from repro.configs.cluster import SimConfig
 from repro.core import metrics, sim_jax, simulator
@@ -93,6 +96,10 @@ class ExperimentResult:
     # under cluster pressure). Nonzero means the run left the
     # deterministic cross-engine parity domain (DESIGN.md §8).
     fallback_count: int = 0
+    # Engine-loop iterations (``State.n_iter``): one ``while_loop``
+    # step each, a tick plus its event jump in event mode. None on the
+    # reference engine.
+    loop_iters: Optional[int] = None
 
 
 def make_config(policy: Optional[str] = None, *,
@@ -135,24 +142,35 @@ def _run_reference(cfg: SimConfig, js: JobSet, mode: str, trace: bool):
     res = simulator.simulate(cfg, js, mode=mode, trace=trace)
     return (metrics.slowdown_table(res), metrics.resched_table(res),
             res.preempted_fraction(), int(res.makespan), res,
-            res.trace, 0, 0)
+            res.trace, 0, 0, None)
 
 
 def _run_jax(cfg: SimConfig, js: JobSet, mode: str, trace: bool,
              trace_capacity: Optional[int]):
-    jobs = sim_jax.jobs_from_jobset(js)
-    st = sim_jax.run_jit(cfg, jobs, cfg.seed, time_mode=mode,
-                         trace=trace, trace_capacity=trace_capacity)
-    summary = sim_jax.result_summary(jobs, st)
-    table = {k: {p: float(v) for p, v in summary[k].items()}
-             for k in ("TE", "BE")}
-    intervals = {p: float(v) for p, v in summary["intervals"].items()}
-    events, overflow = (None, 0)
-    if trace:
-        events, overflow = sim_jax.decode_trace(st)
-    return (table, intervals, float(summary["preempted_frac"]),
-            int(st.t), (jobs, st), events, int(overflow),
-            int(summary["fallback_count"]))
+    """One replay, as the profiler spans ``repro.replay.loop`` (the
+    jobs' upload and the loop program, up to its final clock on the
+    host) and ``repro.replay.summary`` (the summary and its host
+    conversions), then a zero-length ``repro.replay.counts`` span with
+    args ``iters`` and ``jobs``."""
+    with TraceAnnotation("repro.replay.loop"):
+        jobs = sim_jax.jobs_from_jobset(js)
+        st = sim_jax.run_jit(cfg, jobs, cfg.seed, time_mode=mode,
+                             trace=trace, trace_capacity=trace_capacity)
+        t, iters = (int(x) for x in jax.device_get((st.t, st.n_iter)))
+    with TraceAnnotation("repro.replay.summary"):
+        summary = sim_jax.result_summary(jobs, st)
+        table = {k: {p: float(v) for p, v in summary[k].items()}
+                 for k in ("TE", "BE")}
+        intervals = {p: float(v) for p, v in summary["intervals"].items()}
+        events, overflow = (None, 0)
+        if trace:
+            events, overflow = sim_jax.decode_trace(st)
+        out = (table, intervals, float(summary["preempted_frac"]), t,
+               (jobs, st), events, int(overflow),
+               int(summary["fallback_count"]), iters)
+    with TraceAnnotation("repro.replay.counts", iters=iters, jobs=js.n):
+        pass
+    return out
 
 
 def run_experiment(scenario: str = DEFAULT_SCENARIO,
@@ -203,15 +221,16 @@ def run_experiment(scenario: str = DEFAULT_SCENARIO,
     js = scenarios.build(scenario, cfg) if jobs is None else jobs
     if engine == "reference":
         (table, intervals, pf, makespan, raw, events, overflow,
-         fallback) = _run_reference(cfg, js, mode, trace)
+         fallback, iters) = _run_reference(cfg, js, mode, trace)
     else:
         (table, intervals, pf, makespan, raw, events, overflow,
-         fallback) = _run_jax(cfg, js, mode, trace, trace_capacity)
+         fallback, iters) = _run_jax(cfg, js, mode, trace, trace_capacity)
     return ExperimentResult(
         scenario=scenario, policy=cfg.policy, engine=engine, cfg=cfg,
         table=table, intervals=intervals, preempted_frac=pf,
         makespan=makespan, raw=raw, events=events,
-        trace_overflow=overflow, fallback_count=fallback)
+        trace_overflow=overflow, fallback_count=fallback,
+        loop_iters=iters)
 
 
 def run_stream(scenario: str = DEFAULT_SCENARIO,
@@ -270,7 +289,7 @@ def run_stream(scenario: str = DEFAULT_SCENARIO,
         preempted_frac=float(summary["preempted_frac"]),
         makespan=res.makespan, raw=res, events=res.events,
         trace_overflow=res.trace_overflow,
-        fallback_count=res.fallback_count)
+        fallback_count=res.fallback_count, loop_iters=res.loop_iters)
 
 
 def compare_policies(policies, scenario: str = DEFAULT_SCENARIO,

@@ -156,6 +156,13 @@ class State(NamedTuple):
     # when 0, the paper's P cap is exact — sum(max(preempt_count - P,
     # 0)) never exceeds this counter.
     fallback_count: jax.Array
+    # () i32: ``while_loop`` iterations of ``_run_loop`` so far (one per
+    # executed step: a tick, or a tick plus its event jump), carried
+    # across streamed rounds like ``fallback_count``. A performance
+    # counter, not simulation state: tick and event mode reach the same
+    # State in different numbers of iterations, so the parity checks
+    # leave it out (``state_diff_fields``).
+    n_iter: jax.Array
     # In-jit event ring buffer (obs/ring.py layout): (capacity + 1,
     # 4 + n_words) i32 rows [t, code, job, aux, node words...]; the
     # extra row is the dump slot for masked/overflowing writes,
@@ -214,6 +221,7 @@ def init_state(jobs: Jobs, n_nodes: int, node_cap, seed,
                      and jnp.issubdtype(seed.dtype, jax.dtypes.prng_key))
         else jax.random.key(seed),
         fallback_count=jnp.zeros((), jnp.int32),
+        n_iter=jnp.zeros((), jnp.int32),
         ev_buf=jnp.zeros(ev_shape, jnp.int32),
         ev_n=jnp.zeros((), jnp.int32),
     )
@@ -523,6 +531,24 @@ def _signal_one(st: State, jobs: Jobs, v: jax.Array, te: jax.Array,
 # victim selection (registry-dispatched; policies declare jax_rank/jax_score)
 # ---------------------------------------------------------------------------
 
+def _schedule_scope():
+    """``jax.named_scope`` of the schedule pass: the queue pass, the
+    score selection and the fused Pallas kernel inside it. It changes
+    only the ops' ``op_name`` metadata, so a profiler can group the
+    pass's device ops; the compiled program is the same."""
+    return jax.named_scope("schedule_pass")
+
+
+def _in_schedule_scope(fn):
+    """Trace ``fn`` inside :func:`_schedule_scope`."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with _schedule_scope():
+            return fn(*args, **kwargs)
+    return scoped
+
+
+@_in_schedule_scope
 def _score_select(st: State, jobs: Jobs, te: jax.Array, pol, node_cap, s,
                   P, backend: str):
     """Generic score-policy selection -> (state with advanced rng, victim).
@@ -744,6 +770,7 @@ def _make_queue_pass(jobs: Jobs, backfill: bool):
     fit); with backfill it is the first FITTING job in key order and
     ``nskip`` counts the non-fitting jobs ahead of it (the bounded
     scan depth the reference consumes before placing it)."""
+    @_in_schedule_scope
     def queue_pass(st: State, be_mask: jax.Array) -> _Pass:
         fits_b = jnp.all(st.free[None, :, :]
                          >= jobs.demand[:, None, :] - _EPS, axis=2)
@@ -1441,8 +1468,12 @@ def _run_loop(cfg: SimConfig, jobs: Jobs, st: State, max_ticks: int,
             c = c & (carry[0].t < round_end)
         return c
 
+    def body(carry):
+        st, cache = step(carry)
+        return st._replace(n_iter=st.n_iter + 1), cache
+
     st, _ = jax.lax.while_loop(
-        cond, step, (st, _cache_from_state(jobs, st, round_end)))
+        cond, body, (st, _cache_from_state(jobs, st, round_end)))
     return st
 
 
@@ -1545,12 +1576,17 @@ def decode_trace(st: State):
 
 def state_diff_fields(a: State, b: State) -> list:
     """Names of State fields that differ bitwise — rng keys compared by
-    key data. Empty list == full-State bit equality, THE tick-vs-event
-    parity contract; the engine benchmark and the parity/property
-    suites all share this one definition so a new State field is
-    covered everywhere at once."""
+    key data, the ``n_iter`` counter left out. Empty list == full-State
+    bit equality, THE tick-vs-event parity contract; the engine
+    benchmark and the parity/property suites all share this one
+    definition so a new State field is covered everywhere at once."""
     diff = []
     for f in a._fields:
+        if f == "n_iter":
+            # a loop-iteration count, not simulation state: tick mode,
+            # event mode and single-stepping (``make_tick``) reach the
+            # same State in different numbers of iterations
+            continue
         x, y = getattr(a, f), getattr(b, f)
         if f == "rng":
             x, y = jax.random.key_data(x), jax.random.key_data(y)

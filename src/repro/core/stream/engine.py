@@ -51,6 +51,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.cluster import SimConfig
 from repro.core import sim_jax, workload
@@ -230,6 +231,8 @@ class StreamResult:
     # the pool and packing was delayed (module docstring).
     n_spilled: int = 0
     spill_peak: int = 0
+    # engine-loop iterations over the whole run (``State.n_iter``)
+    loop_iters: int = 0
 
     def slowdown(self) -> np.ndarray:
         waiting = self.finish - self.submit - self.exec_total
@@ -335,10 +338,10 @@ class StreamEngine:
             f: np.concatenate([getattr(js, f) for js in parts])
             for f in _FIELDS})
 
-    def _pack_round(self, jobs, st, state_h):
+    def _pack_round(self, jobs, st, state_h, t: int):
         """Recycle free slots with the next arrivals (spill queue
         first); returns the updated pool and the round boundary (next
-        unpacked submit)."""
+        unpacked submit). ``t`` is ``st.t`` on the host."""
         cap = self.capacity
         # a DONE TE slot referenced by an in-grace victim is NOT
         # recyclable: its vacate still decrements te_pending there
@@ -382,16 +385,15 @@ class StreamEngine:
         nxt = self._spill.peek_submit()
         if nxt is None:
             nxt = self.source.peek_submit()
-        if (nxt is not None and nxt <= int(st.t)
-                and free.size - n_packed == 0):
+        if nxt is not None and nxt <= t and free.size - n_packed == 0:
             # saturated: arrivals are overdue and every slot is busy.
             # Move the whole due prefix to the host spill queue (stream
             # order preserved) and shrink the round to one tick so the
             # next pack sees freshly freed slots as soon as possible.
-            moved = self.source.take_due(int(st.t))
+            moved = self.source.take_due(t)
             if moved is not None:
                 self._spill.push(moved)
-            nxt = int(st.t) + 1
+            nxt = t + 1
         return jobs, st, nxt
 
     def _pad(self, a, dtype):
@@ -402,8 +404,6 @@ class StreamEngine:
     def _drain_events(self, st):
         """Decode + slot->gid remap this round's ring; returns the
         State with ``ev_n`` reset for the next round."""
-        if not self.trace:
-            return st
         events, overflow = obs_ring.decode_ring(st.ev_buf, st.ev_n)
         self._overflow += int(overflow)
         gid = self._slot_gid
@@ -447,6 +447,13 @@ class StreamEngine:
     # -- the macro-round loop ----------------------------------------
 
     def run(self) -> StreamResult:
+        """The macro-round loop. Each round is a ``repro.stream.round``
+        profiler span (arg ``round``) tiled by its phases:
+        ``repro.stream.pack``, ``.run``, ``.drain`` (traced runs only)
+        and ``.harvest``; a zero-length ``repro.stream.counts`` span
+        closes it with the round's counts as args. The last round
+        span, which finds nothing left to run, holds only its pack.
+        Without a profiler session each span costs a level check."""
         cfg, cap = self.cfg, self.capacity
         n_nodes = cfg.cluster.n_nodes
         jobs = _empty_pool(cap, n_nodes)
@@ -455,34 +462,54 @@ class StreamEngine:
             trace_capacity=self.trace_capacity if self.trace else 0)
         self._reset()
         rounds, n_done, max_live = 0, 0, 0
+        t, n_iter = 0, 0                   # st.t and st.n_iter on the host
 
         while True:
-            state_h = np.asarray(st.state)
-            jobs, st, nxt = self._pack_round(jobs, st, state_h)
-            live = cap - int(st.n_done)
-            max_live = max(max_live, live)
-            if live == 0 and nxt is None:
-                break                      # drained: nothing left anywhere
-            before = (int(st.t), n_done, self._n_seen)
-            st = sim_jax.run_round(cfg, jobs, st, round_end=nxt,
-                                   time_mode=self.time_mode,
-                                   trace=self.trace)
-            rounds += 1
-            if int(st.t) >= _MAX_TICKS:
-                raise RuntimeError(
-                    f"streamed run stalled: t reached the {_MAX_TICKS}"
-                    "-tick terminal with jobs unfinished")
-            st = self._drain_events(st)
-            state_h = np.asarray(st.state)
-            n_done += self._harvest(jobs, st, state_h)
-            if (int(st.t), n_done, self._n_seen) == before:
-                raise RuntimeError(
-                    "streamed run made no progress in a round "
-                    f"(t={int(st.t)}, done={n_done}) — engine bug")
+            with TraceAnnotation("repro.stream.round", round=rounds):
+                with TraceAnnotation("repro.stream.pack"):
+                    seen, spilled = self._n_seen, self._spill.total
+                    state_h = np.asarray(st.state)
+                    jobs, st, nxt = self._pack_round(jobs, st, state_h, t)
+                    live = cap - int(st.n_done)
+                    max_live = max(max_live, live)
+                if live == 0 and nxt is None:
+                    break                  # drained: nothing left anywhere
+                before = (t, n_done, self._n_seen)
+                with TraceAnnotation("repro.stream.run"):
+                    st = sim_jax.run_round(cfg, jobs, st, round_end=nxt,
+                                           time_mode=self.time_mode,
+                                           trace=self.trace)
+                    t, iters = (int(x) for x in
+                                jax.device_get((st.t, st.n_iter)))
+                    if t >= _MAX_TICKS:
+                        raise RuntimeError(
+                            f"streamed run stalled: t reached the "
+                            f"{_MAX_TICKS}-tick terminal with jobs "
+                            "unfinished")
+                if self.trace:
+                    with TraceAnnotation("repro.stream.drain"):
+                        st = self._drain_events(st)
+                with TraceAnnotation("repro.stream.harvest"):
+                    state_h = np.asarray(st.state)
+                    harvested = self._harvest(jobs, st, state_h)
+                    n_done += harvested
+                    if (t, n_done, self._n_seen) == before:
+                        raise RuntimeError(
+                            "streamed run made no progress in a round "
+                            f"(t={t}, done={n_done}) — engine bug")
+                with TraceAnnotation(
+                        "repro.stream.counts", round=rounds,
+                        packed=self._n_seen - seen, harvested=harvested,
+                        live=live, iters=iters - n_iter,
+                        spilled=self._spill.total - spilled):
+                    pass
+                rounds += 1
+                n_iter = iters
 
-        return self._finalize(st, rounds, n_done, max_live)
+        return self._finalize(st, rounds, n_done, max_live, n_iter)
 
-    def _finalize(self, st, rounds, n_done, max_live) -> StreamResult:
+    def _finalize(self, st, rounds, n_done, max_live,
+                  loop_iters) -> StreamResult:
         if self.result_sink is None:
             gids = np.concatenate([b["gid"] for b in self._batches]) \
                 if self._batches else np.zeros(0, np.int64)
@@ -504,7 +531,7 @@ class StreamEngine:
             events=(self._events if self.trace
                     and self.event_sink is None else None),
             n_spilled=self._spill.total, spill_peak=self._spill.peak,
-            **cols)
+            loop_iters=loop_iters, **cols)
 
 
 def _reject_spilled(res: StreamResult) -> None:
