@@ -14,7 +14,9 @@ excluded from exact parity (property-tested statistically instead).
 
 Gang (multi-node) jobs: placement state is an ``(n_jobs, n_nodes)``
 boolean assignment mask (``State.assign``) instead of a scalar node
-index, and every job carries its gang width (``Jobs.width``).
+index, and every job carries its gang width (``Jobs.width``). Inside
+the loop every such slots x nodes array is node-major, slots on the
+last axis (``_swap_assign``, DESIGN.md §7).
 Placement is all-or-nothing first-fit — the first ``width`` nodes
 whose free vector covers the PER-NODE demand — the vectorized mirror
 of ``engine/placement.ClusterState.fits_job``. Victims vacate and
@@ -305,33 +307,72 @@ def _gang_fit(free: jax.Array, d: jax.Array, w: jax.Array):
     return ok, mask
 
 
-def _gang_fits(free: jax.Array, demand: jax.Array,
+def _gang_fits(free: jax.Array, need_t: jax.Array,
                width: jax.Array) -> jax.Array:
     """Per-job gang feasibility: (N,) bool, True where at least
-    ``width[j]`` nodes of ``free`` each cover ``demand[j]`` (the
+    ``width[j]`` nodes of ``free`` each cover job j's demand (the
     vectorized form of ``_gang_fit(...)[0]`` over every job at once)."""
-    return _fit_counts(free, demand) >= width
+    return _fit_counts(free, need_t) >= width
 
 
-def _fit_counts(free: jax.Array, demand: jax.Array) -> jax.Array:
+def _fit_counts(free: jax.Array, need_t: jax.Array) -> jax.Array:
     """Per-job count of nodes whose free vector covers the per-node
-    demand: (N,) i32. ``_gang_fits`` is ``counts >= width``; the fused
-    schedule_step kernel computes the same reduction in-tile."""
-    fits = jnp.all(free[None, :, :] >= demand[:, None, :] - _EPS, axis=2)
-    return jnp.sum(fits, axis=1).astype(jnp.int32)
+    demand: (N,) i32. ``need_t`` is the (3, N) demand less the fit
+    epsilon, hoisted out of the loop; the fits are a node-major
+    (nodes, N) tile, one compare per resource. ``_gang_fits`` is
+    ``counts >= width``; the fused schedule_step kernel computes the
+    same reduction in-tile."""
+    fits = free[:, 0:1] >= need_t[0:1, :]
+    for r in (1, 2):
+        fits = fits & (free[:, r:r + 1] >= need_t[r:r + 1, :])
+    return jnp.sum(fits, axis=0).astype(jnp.int32)
 
 
 def _best_victim_node(free: jax.Array, assign: jax.Array,
-                      demand: jax.Array, te_d: jax.Array):
+                      demand_t: jax.Array, te_d: jax.Array):
     """Eq. 2 glue (``engine/preemption.best_victim_node``): for every
     job, the min-slack of ``free + own demand - te_demand`` per node
-    masked to the job's assigned nodes, and the argmax node — the node
-    a multi-node victim is evaluated (and accounted) against. Rows
-    with no assignment get ``-inf`` slack (never eligible)."""
-    slack = jnp.min(free[None, :, :] + demand[:, None, :]
-                    - te_d[None, None, :], axis=2)          # (N, nodes)
+    masked to the job's assigned nodes (``assign`` node-major), and the
+    argmax node — the node a multi-node victim is evaluated (and
+    accounted) against. Jobs with no assignment get ``-inf`` slack
+    (never eligible)."""
+    slack = free[:, 0:1] + demand_t[0:1, :] - te_d[0]       # (nodes, N)
+    for r in (1, 2):
+        slack = jnp.minimum(
+            slack, free[:, r:r + 1] + demand_t[r:r + 1, :] - te_d[r])
     slack = jnp.where(assign, slack, -_INF)
-    return jnp.max(slack, axis=1), jnp.argmax(slack, axis=1)
+    return jnp.max(slack, axis=0), jnp.argmax(slack, axis=0)
+
+
+_LANES = 128   # TPU vector lanes: the slot axis of every in-loop tile
+
+
+def _slot_block(x: jax.Array, j: jax.Array):
+    """The lane-aligned block of slots (last axis of ``x``) that holds
+    slot ``j``: (block, start, one-hot of j within the block). Reading
+    or writing one slot through its 128-slot block keeps the slot axis
+    on the lanes; a one-slot slice makes the TPU compiler transpose the
+    whole tile to reach it."""
+    n = x.shape[-1]
+    w = min(_LANES, n)
+    start = jnp.clip(j - j % w, 0, n - w)
+    blk = jax.lax.dynamic_slice_in_dim(x, start, w, axis=x.ndim - 1)
+    return blk, start, jnp.arange(w) == j - start
+
+
+def _slot_col(x: jax.Array, j: jax.Array) -> jax.Array:
+    """Slot ``j`` of a node-major ``x`` (rows, N): ``x[:, j]``."""
+    blk, _, hit = _slot_block(x, j)
+    if x.dtype == jnp.bool_:
+        return jnp.any(blk & hit, axis=-1)
+    return jnp.sum(jnp.where(hit, blk, 0), axis=-1).astype(x.dtype)
+
+
+def _set_slot_col(x: jax.Array, j: jax.Array, col: jax.Array) -> jax.Array:
+    """``x.at[:, j].set(col)`` for a node-major ``x`` (rows, N)."""
+    blk, start, hit = _slot_block(x, j)
+    blk = jnp.where(hit, col[:, None], blk)
+    return jax.lax.dynamic_update_slice_in_dim(x, blk, start, axis=x.ndim - 1)
 
 
 def _onehot(N: int, j: jax.Array) -> jax.Array:
@@ -362,13 +403,15 @@ def _argmax_key(mask: jax.Array, val, akey) -> jax.Array:
     return jnp.argmin(jnp.where(tied, akey, _INF)).astype(jnp.int32)
 
 
-def _gang_release(assign: jax.Array, demand: jax.Array,
+def _gang_release(assign: jax.Array, demand_t: jax.Array,
                   mask: jax.Array) -> jax.Array:
     """Summed per-node demand of the ``mask``-selected jobs over their
-    assigned nodes: (nodes, 3). One matmul replaces the scalar-node
-    scatter-add (exact for the integer/quantized demands)."""
-    sel = (assign & mask[:, None]).astype(demand.dtype)
-    return sel.T @ demand
+    assigned nodes (``assign`` node-major): (nodes, 3). One masked sum
+    over the slots per resource replaces the scalar-node scatter-add
+    (exact for the integer/quantized demands)."""
+    sel = assign & mask[None, :]
+    return jnp.stack([jnp.sum(jnp.where(sel, demand_t[r][None, :], 0.0),
+                              axis=1) for r in range(3)], axis=1)
 
 
 # -- in-jit event tracing (obs/ring.py layout; DESIGN.md §8) ----------------
@@ -458,12 +501,12 @@ def _ev_scan(st: State, tc: _TraceCtx, t, code, mask) -> State:
     return st
 
 
-def _place(st: State, jobs: Jobs, j: jax.Array, nodes: jax.Array,
+def _place(st: State, demand_t: jax.Array, j: jax.Array, nodes: jax.Array,
            tc: _TraceCtx = None) -> State:
     """Start job j on the ``nodes`` mask (assumes the gang fits).
-    Scatter (row-indexed) updates, not full-array wheres — this runs
-    once per placement inside the schedule while-loops, so it must not
-    pay O(N) per job started."""
+    Slot-indexed updates (``assign`` through j's lane block), not
+    full-array wheres — this runs once per placement inside the
+    schedule while-loops, so it must not pay O(N) per job started."""
     resumed = st.awaiting_resume[j]
     if tc is not None:
         st = _ev1(st, tc, st.t,
@@ -471,9 +514,9 @@ def _place(st: State, jobs: Jobs, j: jax.Array, nodes: jax.Array,
                   j, nodes=nodes)
     return st._replace(
         state=st.state.at[j].set(RUNNING),
-        assign=st.assign.at[j].set(nodes),
+        assign=_set_slot_col(st.assign, j, nodes),
         queue_key=st.queue_key.at[j].set(_INF),
-        free=st.free - jobs.demand[j][None, :]
+        free=st.free - _slot_col(demand_t, j)[None, :]
         * nodes[:, None].astype(jnp.float32),
         last_resume=st.last_resume.at[j].set(
             jnp.where(resumed, st.t, st.last_resume[j])),
@@ -481,8 +524,8 @@ def _place(st: State, jobs: Jobs, j: jax.Array, nodes: jax.Array,
     )
 
 
-def _signal_one(st: State, jobs: Jobs, v: jax.Array, te: jax.Array,
-                tc: _TraceCtx = None) -> State:
+def _signal_one(st: State, jobs: Jobs, demand_t: jax.Array, v: jax.Array,
+                te: jax.Array, tc: _TraceCtx = None) -> State:
     """Signal preemption of running BE job v for TE job te (scalars).
     Gang victims promise / vacate ALL their nodes at once.
 
@@ -491,7 +534,7 @@ def _signal_one(st: State, jobs: Jobs, v: jax.Array, te: jax.Array,
     per-victim scatters selected by the scalar ``gp0`` — one row write
     per field instead of the old two-full-State ``tree.map`` select, so
     a signal costs O(nodes), not O(N)."""
-    row = st.assign[v]
+    row = _slot_col(st.assign, v)
     gp0 = jobs.gp[v] == 0
     if tc is not None:
         # SIGNAL always; a GP=0 victim vacates and requeues inline
@@ -503,14 +546,14 @@ def _signal_one(st: State, jobs: Jobs, v: jax.Array, te: jax.Array,
         st = _ev_append(
             st, _ev_rows(tc, st.t, codes, v3, aux=aux3),
             jnp.stack([jnp.asarray(True), gp0, gp0]))
-    d = jobs.demand[v][None, :] * row[:, None].astype(jnp.float32)
+    d = _slot_col(demand_t, v)[None, :] * row[:, None].astype(jnp.float32)
     zero = jnp.zeros_like(d)
     return st._replace(
         preempt_count=st.preempt_count.at[v].add(1),
         last_signal=st.last_signal.at[v].set(st.t),
         awaiting_resume=st.awaiting_resume.at[v].set(True),
         state=st.state.at[v].set(jnp.where(gp0, QUEUED, GRACE)),
-        assign=st.assign.at[v].set(row & ~gp0),
+        assign=_set_slot_col(st.assign, v, row & ~gp0),
         queue_key=st.queue_key.at[v].set(
             jnp.where(gp0, st.top_key, st.queue_key[v])),
         top_key=jnp.where(gp0, st.top_key - 1.0, st.top_key),
@@ -549,8 +592,8 @@ def _in_schedule_scope(fn):
 
 
 @_in_schedule_scope
-def _score_select(st: State, jobs: Jobs, te: jax.Array, pol, node_cap, s,
-                  P, backend: str):
+def _score_select(st: State, jobs: Jobs, demand_t: jax.Array, te: jax.Array,
+                  pol, node_cap, s, P, backend: str):
     """Generic score-policy selection -> (state with advanced rng, victim).
 
     The policy's ``jax_score`` gives per-job scores (lower = better
@@ -560,21 +603,22 @@ def _score_select(st: State, jobs: Jobs, te: jax.Array, pol, node_cap, s,
     masked argmin, with the paper's random-candidate fallback when no
     job passes the masks. ``backend != "jnp"`` fuses score, best-node
     reduction and masked argmin on the policy's registered accelerated
-    kernel (``jax_score_accel``; returns -1 when nothing passes).
+    kernel (``jax_score_accel``; returns -1 when nothing passes), which
+    takes ``assign`` slot-major as every kernel backend does.
     """
     cand = (st.state == RUNNING) & ~jobs.is_te
     under = st.preempt_count < P
     if backend != "jnp":
         be_q = (st.state == QUEUED) & ~jobs.is_te
-        main = pol.jax_score_accel(backend, jobs, te, st.free, st.assign,
+        main = pol.jax_score_accel(backend, jobs, te, st.free, st.assign.T,
                                    cand, under, node_cap, s,
                                    pending_free=st.pending_free,
                                    queue_key=st.queue_key, be_q=be_q)
         mask_any = main >= 0
     else:
         score = pol.jax_score(jobs, cand, node_cap, s)
-        best_slack, _ = _best_victim_node(st.free, st.assign, jobs.demand,
-                                          jobs.demand[te])
+        best_slack, _ = _best_victim_node(st.free, st.assign, demand_t,
+                                          _slot_col(demand_t, te))
         elig = best_slack >= -_EPS
         mask = cand & elig & under
         main = _argmin_key(mask, score, jobs.akey)
@@ -604,8 +648,9 @@ def _resolve_score_backend(cfg: SimConfig, spec) -> str:
     return backend if backend in spec.score_backends else "jnp"
 
 
-def _until_fits_select(st: State, jobs: Jobs, te: jax.Array, rank_val,
-                       P, tc: _TraceCtx = None) -> State:
+def _until_fits_select(st: State, jobs: Jobs, demand_t: jax.Array,
+                       te: jax.Array, rank_val, P,
+                       tc: _TraceCtx = None) -> State:
     """LRTP/RAND: keep signalling victims (best ``rank_val`` first,
     under-P-cap first) until the TE fits on the last victim's BEST
     node, counting the demand signalled there so far. Mirrors
@@ -614,10 +659,10 @@ def _until_fits_select(st: State, jobs: Jobs, te: jax.Array, rank_val,
     best_victim_node`` would pick (their only node when single-node),
     chosen once from the free vectors at trigger time."""
     N = jobs.submit.shape[0]
-    te_d = jobs.demand[te]
+    te_d = _slot_col(demand_t, te)
     n_nodes = st.free.shape[0]
     free0 = st.free                                # invocation snapshot
-    _, best_node = _best_victim_node(free0, st.assign, jobs.demand, te_d)
+    _, best_node = _best_victim_node(free0, st.assign, demand_t, te_d)
 
     def cond(carry):
         st, taken, pending, satisfied = carry
@@ -637,13 +682,13 @@ def _until_fits_select(st: State, jobs: Jobs, te: jax.Array, rank_val,
         node = best_node[v]
         st = st._replace(
             fallback_count=st.fallback_count + (~m1.any()).astype(jnp.int32))
-        st = _signal_one(st, jobs, v, te, tc)
+        st = _signal_one(st, jobs, demand_t, v, te, tc)
         # Accumulate each selection's demand at its best node and test
         # the TE there against the snapshot — mirrors
         # policies._preempt_until_fits (pending starts at free, adds
         # every victim regardless of GP; GP=0 inline vacates are part
         # of that same accounting).
-        pending = pending.at[node].add(jobs.demand[v])
+        pending = pending.at[node].add(_slot_col(demand_t, v))
         satisfied = jnp.all(te_d <= free0[node] + pending[node] + _EPS)
         return st, taken | _onehot(N, v), pending, satisfied
 
@@ -654,8 +699,8 @@ def _until_fits_select(st: State, jobs: Jobs, te: jax.Array, rank_val,
     return st
 
 
-def _gang_select(st: State, jobs: Jobs, te: jax.Array, rank_val, P,
-                 score=None, tc: _TraceCtx = None) -> State:
+def _gang_select(st: State, jobs: Jobs, demand_t: jax.Array, te: jax.Array,
+                 rank_val, P, score=None, tc: _TraceCtx = None) -> State:
     """Multi-node TE: the vectorized mirror of
     ``engine/preemption.gang_select``. With ``score`` (Eq. 4-style
     argmin policies; LOWER = better victim, computed over TOTAL gang
@@ -668,7 +713,7 @@ def _gang_select(st: State, jobs: Jobs, te: jax.Array, rank_val, P,
     budget for no gain). Over-P-cap signals count into
     ``fallback_count`` (the P-cap invariant's allowance)."""
     N = jobs.submit.shape[0]
-    te_d = jobs.demand[te]
+    te_d = _slot_col(demand_t, te)
     w = jobs.width[te]
     free0 = st.free
     cand0 = (st.state == RUNNING) & ~jobs.is_te
@@ -680,10 +725,13 @@ def _gang_select(st: State, jobs: Jobs, te: jax.Array, rank_val, P,
     if score is not None:
         # single-eviction sufficiency: free + the victim's demand on
         # each of its nodes must yield >= width fitting nodes
-        trial = free0[None, :, :] + jobs.demand[:, None, :] \
-            * st.assign[:, :, None].astype(jnp.float32)
-        nfit1 = jnp.sum(jnp.all(trial >= te_d[None, None, :] - _EPS,
-                                axis=2), axis=1)
+        held = st.assign.astype(jnp.float32)                # (nodes, N)
+        fit1 = None
+        for r in range(3):
+            c = (free0[:, r:r + 1] + demand_t[r:r + 1, :] * held
+                 >= te_d[r] - _EPS)
+            fit1 = c if fit1 is None else fit1 & c
+        nfit1 = jnp.sum(fit1, axis=0)
         pool = cand0 & jnp.where((cand0 & under0).any(), under0, True)
         single = pool & (nfit1 >= w)
         v1 = _argmin_key(single, score, jobs.akey)
@@ -705,8 +753,8 @@ def _gang_select(st: State, jobs: Jobs, te: jax.Array, rank_val, P,
         m1 = c & under0
         pick = jnp.where(m1.any(), m1, c)
         v = _argmax_key(pick, rank_val, jobs.akey)
-        pending = pending + jobs.demand[v][None, :] \
-            * st.assign[v][:, None].astype(jnp.float32)
+        pending = pending + _slot_col(demand_t, v)[None, :] \
+            * _slot_col(st.assign, v)[:, None].astype(jnp.float32)
         return (taken | _onehot(N, v), pending, n_fit(pending) >= w,
                 nsel + 1, seq.at[v].set(nsel))
 
@@ -715,28 +763,21 @@ def _gang_select(st: State, jobs: Jobs, te: jax.Array, rank_val, P,
         (jnp.zeros((N,), bool), free0, n_fit(free0) >= w,
          jnp.int32(0), jnp.full((N,), -1, jnp.int32)))
 
-    def signal_single(st):
+    # signal the single victim, or the accumulated set in selection
+    # order (nothing when even that set is insufficient)
+    n_sig = jnp.where(have_single, 1, jnp.where(satisfied, nsel, 0))
+
+    def sig_body(carry):
+        st, k = carry
+        v = jnp.where(have_single, v1,
+                      jnp.argmax(seq == k).astype(jnp.int32))
         st = st._replace(fallback_count=st.fallback_count
-                         + (~under0[v1]).astype(jnp.int32))
-        return _signal_one(st, jobs, v1, te, tc)
+                         + (~under0[v]).astype(jnp.int32))
+        return _signal_one(st, jobs, demand_t, v, te, tc), k + 1
 
-    def signal_accum(st):
-        n_sig = jnp.where(satisfied, nsel, 0)   # insufficient -> nothing
-
-        def sig_cond(carry):
-            return carry[1] < n_sig
-
-        def sig_body(carry):
-            st, k = carry
-            v = jnp.argmax(seq == k).astype(jnp.int32)
-            st = st._replace(fallback_count=st.fallback_count
-                             + (~under0[v]).astype(jnp.int32))
-            return _signal_one(st, jobs, v, te, tc), k + 1
-
-        st, _ = jax.lax.while_loop(sig_cond, sig_body, (st, jnp.int32(0)))
-        return st
-
-    return jax.lax.cond(have_single, signal_single, signal_accum, st)
+    st, _ = jax.lax.while_loop(lambda c: c[1] < n_sig, sig_body,
+                               (st, jnp.int32(0)))
+    return st
 
 
 # ---------------------------------------------------------------------------
@@ -750,9 +791,10 @@ class _Pass(NamedTuple):
     would-act gate, the TE lane and the BE lane inside a single
     while-loop iteration (the TE-dependent half — Eq. 3 score, Eq. 2
     best-node reduction, Eq. 4 argmin — is per-trigger and lives in
-    ``_score_select`` / the fused kernel)."""
-    fits: jax.Array      # (N, M) bool : free covers demand, per node
-    fit_now: jax.Array   # (N,)  i32  : row sums of ``fits``
+    ``_score_select`` / the fused kernel). It holds no (nodes, N) tile:
+    a lane re-derives the one slot's node mask it places from ``free``
+    (``_gang_fit``), which the pass saw unchanged."""
+    fit_now: jax.Array   # (N,)  i32  : nodes whose free covers demand
     fit_pend: jax.Array  # (N,)  i32  : counts vs free + pending_free
     be_pick: jax.Array   # ()    i32  : BE job the lane would try next
     be_can: jax.Array    # ()    bool : the pick exists and fits
@@ -760,9 +802,9 @@ class _Pass(NamedTuple):
     #                                   the pick (backfill scan budget)
 
 
-def _make_queue_pass(jobs: Jobs, backfill: bool):
+def _make_queue_pass(jobs: Jobs, need_t: jax.Array, backfill: bool):
     """Build ``queue_pass(st, be_mask) -> _Pass``: the per-job fit
-    tile against ``free`` (and, bitwise-gated on any pending residue,
+    counts against ``free`` (and, bitwise-gated on any pending residue,
     against ``free + pending_free`` — residue-exact mirror of the full
     promised-capacity evaluation), plus the BE queue scan over
     ``be_mask``. Without backfill the pick is the queue head
@@ -772,12 +814,10 @@ def _make_queue_pass(jobs: Jobs, backfill: bool):
     scan depth the reference consumes before placing it)."""
     @_in_schedule_scope
     def queue_pass(st: State, be_mask: jax.Array) -> _Pass:
-        fits_b = jnp.all(st.free[None, :, :]
-                         >= jobs.demand[:, None, :] - _EPS, axis=2)
-        fit_now = jnp.sum(fits_b, axis=1).astype(jnp.int32)
+        fit_now = _fit_counts(st.free, need_t)
         fit_pend = jax.lax.cond(
             (st.pending_free != 0).any(),
-            lambda: _fit_counts(st.free + st.pending_free, jobs.demand),
+            lambda: _fit_counts(st.free + st.pending_free, need_t),
             lambda: fit_now)
         okj = fit_now >= jobs.width
         if not backfill:
@@ -793,7 +833,7 @@ def _make_queue_pass(jobs: Jobs, backfill: bool):
             pick_key = jnp.where(be_can, st.queue_key[pick], _INF)
             nskip = jnp.sum(be_mask & ~okj
                             & (st.queue_key < pick_key)).astype(jnp.int32)
-        return _Pass(fits_b, fit_now, fit_pend, pick, be_can, nskip)
+        return _Pass(fit_now, fit_pend, pick, be_can, nskip)
 
     return queue_pass
 
@@ -820,8 +860,8 @@ def _make_gate(jobs: Jobs, preemptive: bool, backfill: bool = False,
     return gate
 
 
-def _make_would_act_cached(jobs: Jobs, preemptive: bool,
-                           backfill: bool = False,
+def _make_would_act_cached(jobs: Jobs, need_t: jax.Array,
+                           preemptive: bool, backfill: bool = False,
                            backfill_depth: int = 64):
     """Vectorized mirror of ``SchedulerCore.schedule_would_act``,
     taking the threaded ``_Cache`` so the common no-op evaluation is
@@ -856,24 +896,24 @@ def _make_would_act_cached(jobs: Jobs, preemptive: bool,
         if not backfill:
             head = jnp.argmin(jnp.where(be_q, st.queue_key, _INF))
             ok_head = jnp.sum(jnp.all(
-                st.free >= jobs.demand[head][None, :] - _EPS,
+                st.free >= _slot_col(need_t, head)[None, :],
                 axis=1)) >= jobs.width[head]
             act = be_q.any() & ok_head
         else:
             # the reference scan examines the first `depth` jobs in
             # queue order and acts iff any of them fits
-            fits_all = _gang_fits(st.free, jobs.demand, jobs.width)
+            fits_all = _gang_fits(st.free, need_t, jobs.width)
             order = jnp.argsort(jnp.where(be_q, st.queue_key, _INF))
             scan = order[:depth]
             act = (be_q[scan] & fits_all[scan]).any()
         if preemptive:
             def te_part():
                 te_q = queued & jobs.is_te
-                fits_now = _fit_counts(st.free, jobs.demand) >= jobs.width
+                fits_now = _fit_counts(st.free, need_t) >= jobs.width
                 fits_pend = jax.lax.cond(
                     (st.pending_free != 0).any(),
                     lambda: _fit_counts(st.free + st.pending_free,
-                                        jobs.demand) >= jobs.width,
+                                        need_t) >= jobs.width,
                     lambda: fits_now)
                 has_cand = ((st.state == RUNNING) & ~jobs.is_te).any()
                 trigger = (st.te_pending == 0) & ~fits_pend & has_cand
@@ -891,10 +931,11 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
                max_ticks: int = 1 << 22, trace: bool = False,
                ext_arrival=None):
     """Build the ``(State, _Cache) -> (State, _Cache)`` while-loop
-    body: one scheduling tick, plus — in ``"event"`` time mode — the
-    event jump that compresses the following run of provably no-op
-    ticks into a single ``dt`` step (bit-exact either way; see module
-    docstring and DESIGN.md §7).
+    body over the loop's node-major State (``assign`` (nodes, N), see
+    :func:`_swap_assign`): one scheduling tick, plus — in ``"event"``
+    time mode — the event jump that compresses the following run of
+    provably no-op ticks into a single ``dt`` step (bit-exact either
+    way; see module docstring and DESIGN.md §7).
 
     Every phase is gated so a no-op tick touches as few arrays as
     possible: arrivals and vacates fire only when the cache says their
@@ -930,17 +971,25 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
     pol = spec.make()                  # decision rule (jax declarations)
     backend = _resolve_score_backend(cfg, spec)
     tc = _trace_ctx(n_nodes) if trace else None
+    # loop invariants: every (nodes, N) tile compares against one
+    # (1, N) row per resource (DESIGN.md §7, in-loop layout)
+    demand_t = jobs.demand.T
+    need_t = demand_t - _EPS          # what a node's free must cover
     if preemptive and spec.jax_kind is None:
         raise NotImplementedError(
             f"policy {cfg.policy!r} registers no JAX implementation "
             "(jax_kind); run it on the reference engine")
 
-    def trigger_preemption(st: State, te: jax.Array) -> State:
+    def trigger_preemption(st: State, te: jax.Array, do) -> State:
+        """Victim selection for TE ``te`` where ``do``, else ``st``.
+        One flat three-way switch (skip, width 1, gang): a nested
+        conditional around the width-1 path made the TPU compiler copy
+        the whole ``assign`` tile before the victim's column write."""
         if spec.jax_kind == "score":
             def width1(s_):
-                s_, v = _score_select(s_, jobs, te, pol, node_cap, s, P,
-                                      backend)
-                return _signal_one(s_, jobs, v, te, tc)
+                s_, v = _score_select(s_, jobs, demand_t, te, pol,
+                                      node_cap, s, P, backend)
+                return _signal_one(s_, jobs, demand_t, v, te, tc)
 
             def gang(s_):
                 # gang ordering keys on the score of the TOTAL gang
@@ -952,25 +1001,25 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
                     demand=jobs.demand * jobs.width[:, None]
                     .astype(jnp.float32))
                 gscore = pol.jax_score(total, cand, node_cap, s)
-                return _gang_select(s_, jobs, te, -gscore, P, score=gscore,
-                                    tc=tc)
+                return _gang_select(s_, jobs, demand_t, te, -gscore, P,
+                                    score=gscore, tc=tc)
+        else:
+            def width1(s_):
+                s_, rank = pol.jax_rank(s_, jobs)  # may consume s_.rng
+                return _until_fits_select(s_, jobs, demand_t, te, rank, P,
+                                          tc)
 
-            return jax.lax.cond(jobs.width[te] == 1, width1, gang, st)
+            def gang(s_):
+                s_, rank = pol.jax_rank(s_, jobs)  # may consume s_.rng
+                return _gang_select(s_, jobs, demand_t, te, rank, P, tc=tc)
 
-        def width1(s_):
-            s_, rank = pol.jax_rank(s_, jobs)      # may consume s_.rng
-            return _until_fits_select(s_, jobs, te, rank, P, tc)
+        branch = jnp.where(do, jnp.where(jobs.width[te] == 1, 1, 2), 0)
+        return jax.lax.switch(branch, (lambda s_: s_, width1, gang), st)
 
-        def gang(s_):
-            s_, rank = pol.jax_rank(s_, jobs)      # may consume s_.rng
-            return _gang_select(s_, jobs, te, rank, P, tc=tc)
-
-        return jax.lax.cond(jobs.width[te] == 1, width1, gang, st)
-
-    queue_pass = _make_queue_pass(jobs, cfg.backfill)
+    queue_pass = _make_queue_pass(jobs, need_t, cfg.backfill)
     gate = _make_gate(jobs, preemptive, cfg.backfill, cfg.backfill_depth)
-    would_act = _make_would_act_cached(jobs, preemptive, cfg.backfill,
-                                       cfg.backfill_depth)
+    would_act = _make_would_act_cached(jobs, need_t, preemptive,
+                                       cfg.backfill, cfg.backfill_depth)
 
     def head_mask(st):
         q = st.state == QUEUED
@@ -1007,28 +1056,24 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
             # processed together with j itself
             q = (st.state == QUEUED) & jobs.is_te & ~processed
             processed = processed | (q & (st.queue_key <= st.queue_key[j]))
-            ok = ps.fit_now[j] >= jobs.width[j]
-            row = ps.fits[j]
-            nodes = row & (jnp.cumsum(row) <= jobs.width[j]) & ok
+            d = _slot_col(demand_t, j)
+            ok, nodes = _gang_fit(st.free, d, jobs.width[j])
 
             def place(st):
-                return _place(st, jobs, j, nodes, tc)
+                return _place(st, demand_t, j, nodes, tc)
 
             def blocked(st):
                 fits_pending = ps.fit_pend[j] >= jobs.width[j]
                 has_cand = ((st.state == RUNNING) & ~jobs.is_te).any()
                 do = (st.te_pending[j] == 0) & ~fits_pending & has_cand
-                st = jax.lax.cond(do,
-                                  lambda s_: trigger_preemption(s_, j),
-                                  lambda s_: s_, st)
+                st = trigger_preemption(st, j, do)
                 # GP=0 victims vacate inline: place the TE NOW, before
                 # the BE pass can reclaim the freed nodes (mirrors the
                 # reference).
-                ok2, nodes2 = _gang_fit(st.free, jobs.demand[j],
-                                        jobs.width[j])
+                ok2, nodes2 = _gang_fit(st.free, d, jobs.width[j])
                 return jax.lax.cond(do & ok2,
-                                    lambda s_: _place(s_, jobs, j, nodes2,
-                                                      tc),
+                                    lambda s_: _place(s_, demand_t, j,
+                                                      nodes2, tc),
                                     lambda s_: s_, st)
 
             st = jax.lax.cond(ok, place, blocked, st)
@@ -1044,15 +1089,15 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
 
     def be_queue(st: State, ps: _Pass):
         """FIFO head-of-line BE lane: place the head while it fits
-        (the pass already holds the head's identity, fit verdict and
-        node-fit row — the body is one placement scatter plus the
-        pass refresh)."""
+        (the pass already holds the head's identity and fit verdict —
+        the body is the head's node mask, one placement scatter and
+        the pass refresh)."""
         def body(carry):
             st, ps = carry
             j = ps.be_pick
-            row = ps.fits[j]
-            nodes = row & (jnp.cumsum(row) <= jobs.width[j])
-            st = _place(st, jobs, j, nodes, tc)
+            _, nodes = _gang_fit(st.free, _slot_col(demand_t, j),
+                                 jobs.width[j])
+            st = _place(st, demand_t, j, nodes, tc)
             ps = queue_pass(st, head_mask(st))
             return st, ps
 
@@ -1080,9 +1125,9 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
             skipped = skipped | (q & (ps.fit_now < jobs.width)
                                  & (st.queue_key < st.queue_key[j]))
             scanned = scanned + ps.nskip
-            row = ps.fits[j]
-            nodes = row & (jnp.cumsum(row) <= jobs.width[j])
-            st = _place(st, jobs, j, nodes, tc)
+            _, nodes = _gang_fit(st.free, _slot_col(demand_t, j),
+                                 jobs.width[j])
+            st = _place(st, demand_t, j, nodes, tc)
             if tc is not None:
                 # marker after a placement that skipped ahead; aux =
                 # cumulative skips this pass (reference `scanned`)
@@ -1176,7 +1221,7 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
             n_vac = jnp.sum(vac)
             te_dec = jnp.zeros((N,), jnp.int32).at[
                 jnp.where(vac, st.victim_of, N)].add(1, mode="drop")
-            freed = _gang_release(st.assign, jobs.demand, vac)
+            freed = _gang_release(st.assign, demand_t, vac)
             st = st._replace(
                 queue_key=jnp.where(
                     vac, st.top_key - rank.astype(jnp.float32),
@@ -1187,7 +1232,7 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
                 last_vacate=jnp.where(vac, st.t, st.last_vacate),
                 te_pending=st.te_pending - te_dec,
                 victim_of=jnp.where(vac, -1, st.victim_of),
-                assign=st.assign & ~vac[:, None],
+                assign=st.assign & ~vac[None, :],
                 state=jnp.where(vac, QUEUED, st.state),
             )
             in_grace = st.state == GRACE
@@ -1228,9 +1273,9 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
         return st, cache, gate(st, ps)
 
     def run_minute(st: State, cache: _Cache):
-        """Decrement running clocks, record finishers (one scatter per
-        finishing job, behind an ``nfin > 0`` gate), decrement grace
-        clocks (gated on any grace job existing)."""
+        """Decrement running clocks, retire finishers (one bulk update,
+        behind an ``nfin > 0`` gate), decrement grace clocks (gated on
+        any grace job existing)."""
         running = st.state == RUNNING
         remaining = st.remaining - running.astype(jnp.int32)
         fin = running & (remaining <= 0)
@@ -1239,26 +1284,15 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
 
         def finish_all(args):
             st, fin = args
-
-            def fbody(carry):
-                st, f = carry
-                j = jnp.argmax(f).astype(jnp.int32)
-                row = st.assign[j]
-                if tc is not None:
-                    st = _ev1(st, tc, st.t + 1, obs_schema.FINISH, j)
-                st = st._replace(
-                    state=st.state.at[j].set(DONE),
-                    finish=st.finish.at[j].set(st.t + 1),
-                    free=st.free + jobs.demand[j][None, :]
-                    * row[:, None].astype(jnp.float32),
-                    assign=st.assign.at[j].set(jnp.zeros_like(row)),
-                    n_done=st.n_done + 1,
-                )
-                return st, f.at[j].set(False)
-
-            st, _ = jax.lax.while_loop(lambda c: c[1].any(), fbody,
-                                       (st, fin))
-            return st
+            if tc is not None:
+                st = _ev_scan(st, tc, st.t + 1, obs_schema.FINISH, fin)
+            return st._replace(
+                state=jnp.where(fin, DONE, st.state),
+                finish=jnp.where(fin, st.t + 1, st.finish),
+                free=st.free + _gang_release(st.assign, demand_t, fin),
+                assign=st.assign & ~fin[None, :],
+                n_done=st.n_done + nfin,
+            )
 
         st = jax.lax.cond(nfin > 0, finish_all, lambda args: args[0],
                           (st, fin))
@@ -1281,83 +1315,85 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
         False only when ``would_act`` provably stays False), so free
         vectors, queues and the rng stream cannot change before the
         event; ``last_*`` metrics need no adjustment because every
-        tick that records them still executes. Plain array math: under
+        tick that records them still executes. A held step (``hold``:
+        the next tick acts) advances ``dt = 0``. Plain array math: under
         ``vmap`` the jump is per-lane."""
-        def fire(st):
-            t1 = st.t
-            running = st.state == RUNNING
-            in_grace = st.state == GRACE
-            # Deltas from t1 (all >= 0): a NOT_ARRIVED job queues at
-            # the top of tick submit; a running job with remaining r
-            # finishes during tick t1 + r - 1; a GRACE job vacates at
-            # the cached expiry. No events pending at all -> jump to
-            # max_ticks (the tick loop's stall terminal).
-            d_arr = cache.next_arrival - t1
-            d_vac = cache.next_vacate - t1
-            d_ev = jnp.minimum(d_arr, d_vac)
+        t1 = st.t
+        running = st.state == RUNNING
+        in_grace = st.state == GRACE
+        # Deltas from t1 (all >= 0): a NOT_ARRIVED job queues at
+        # the top of tick submit; a running job with remaining r
+        # finishes during tick t1 + r - 1; a GRACE job vacates at
+        # the cached expiry. No events pending at all -> jump to
+        # max_ticks (the tick loop's stall terminal).
+        d_arr = cache.next_arrival - t1
+        d_vac = cache.next_vacate - t1
+        d_ev = jnp.minimum(d_arr, d_vac)
 
-            def drain(st):
-                # Nothing queued: would_act stays False no matter what
-                # finishes (every act needs a queued job), so jump
-                # straight to the next arrival / grace expiry and
-                # retire EVERY finish on the way in one bulk update —
-                # k consecutive finish events collapse into this one
-                # iteration. With nothing left to arrive or vacate,
-                # land on the last finish instead (the loop's natural
-                # terminal boundary, same t as tick mode).
-                last_fin = jnp.max(jnp.where(running, st.remaining, 0))
-                dt = jnp.where(d_ev >= _BIG - t1, last_fin,
-                               jnp.clip(d_ev, 0,
-                                        jnp.maximum(big - t1, 0)))
-                dt = dt.astype(jnp.int32)
-                fin = running & (st.remaining <= dt)
-                if tc is not None:
-                    # the bulk retire must emit the FINISH rows the
-                    # skipped ticks would have: sorted by finish time,
-                    # job-index order within a tick (first-occurrence
-                    # argmin) — bitwise identical to tick mode's
-                    # stream, one row per retired job (``_ev_scan``
-                    # rationale)
-                    ft = jnp.where(fin, t1 + st.remaining, _BIG)
+        def drain(st):
+            # Nothing queued: would_act stays False no matter what
+            # finishes (every act needs a queued job), so jump
+            # straight to the next arrival / grace expiry and
+            # retire EVERY finish on the way in one bulk update —
+            # k consecutive finish events collapse into this one
+            # iteration. With nothing left to arrive or vacate,
+            # land on the last finish instead (the loop's natural
+            # terminal boundary, same t as tick mode).
+            last_fin = jnp.max(jnp.where(running, st.remaining, 0))
+            dt = jnp.where(d_ev >= _BIG - t1, last_fin,
+                           jnp.clip(d_ev, 0,
+                                    jnp.maximum(big - t1, 0)))
+            dt = dt.astype(jnp.int32)
+            fin = running & (st.remaining <= dt)
+            if tc is not None:
+                # the bulk retire must emit the FINISH rows the
+                # skipped ticks would have: sorted by finish time,
+                # job-index order within a tick (first-occurrence
+                # argmin) — bitwise identical to tick mode's
+                # stream, one row per retired job (``_ev_scan``
+                # rationale)
+                ft = jnp.where(fin, t1 + st.remaining, _BIG)
 
-                    def dbody(carry):
-                        st, ftm = carry
-                        j = jnp.argmin(ftm).astype(jnp.int32)
-                        st = _ev1(st, tc, ftm[j], obs_schema.FINISH, j)
-                        return st, ftm.at[j].set(_BIG)
+                def dbody(carry):
+                    st, ftm = carry
+                    j = jnp.argmin(ftm).astype(jnp.int32)
+                    st = _ev1(st, tc, ftm[j], obs_schema.FINISH, j)
+                    return st, ftm.at[j].set(_BIG)
 
-                    st, _ = jax.lax.while_loop(
-                        lambda c: (c[1] < _BIG).any(), dbody, (st, ft))
-                return st._replace(
-                    t=t1 + dt,
-                    remaining=st.remaining - jnp.where(
-                        fin, st.remaining, dt * running.astype(jnp.int32)),
-                    state=jnp.where(fin, DONE, st.state),
-                    finish=jnp.where(fin, t1 + st.remaining, st.finish),
-                    free=st.free + _gang_release(st.assign, jobs.demand,
-                                                 fin),
-                    assign=st.assign & ~fin[:, None],
-                    n_done=st.n_done + jnp.sum(fin),
-                    grace_left=st.grace_left
-                    - dt * in_grace.astype(jnp.int32),
-                )
+                st, _ = jax.lax.while_loop(
+                    lambda c: (c[1] < _BIG).any(), dbody, (st, ft))
+            return st._replace(
+                t=t1 + dt,
+                remaining=st.remaining - jnp.where(
+                    fin, st.remaining, dt * running.astype(jnp.int32)),
+                state=jnp.where(fin, DONE, st.state),
+                finish=jnp.where(fin, t1 + st.remaining, st.finish),
+                free=st.free + _gang_release(st.assign, demand_t,
+                                             fin),
+                assign=st.assign & ~fin[None, :],
+                n_done=st.n_done + jnp.sum(fin),
+                grace_left=st.grace_left
+                - dt * in_grace.astype(jnp.int32),
+            )
 
-            def normal(st):
-                d_fin = jnp.min(jnp.where(running, st.remaining - 1, big))
-                dt = jnp.minimum(d_ev, d_fin)
-                dt = jnp.clip(dt, 0, jnp.maximum(big - t1, 0)) \
-                    .astype(jnp.int32)
-                return st._replace(
-                    t=t1 + dt,
-                    remaining=st.remaining
-                    - dt * running.astype(jnp.int32),
-                    grace_left=st.grace_left
-                    - dt * in_grace.astype(jnp.int32),
-                )
+        def normal(st):
+            d_fin = jnp.min(jnp.where(running, st.remaining - 1, big))
+            dt = jnp.minimum(d_ev, d_fin)
+            dt = jnp.clip(dt, 0, jnp.maximum(big - t1, 0))
+            dt = jnp.where(hold, 0, dt).astype(jnp.int32)
+            return st._replace(
+                t=t1 + dt,
+                remaining=st.remaining
+                - dt * running.astype(jnp.int32),
+                grace_left=st.grace_left
+                - dt * in_grace.astype(jnp.int32),
+            )
 
-            return jax.lax.cond(cache.n_queued == 0, drain, normal, st)
+        # a held step is ``normal`` with dt = 0: no separate
+        # pass-through branch, which would copy every State array
+        return jax.lax.cond(~hold & (cache.n_queued == 0), drain, normal,
+                            st)
 
-        return jax.lax.cond(hold, lambda st: st, fire, st)
 
     def step(carry):
         st, cache = carry
@@ -1393,6 +1429,16 @@ def _make_step(cfg: SimConfig, jobs: Jobs, n_nodes: int,
     return step
 
 
+def _swap_assign(st: State) -> State:
+    """Transpose ``assign`` between the State's public (N, nodes)
+    layout and the loop's node-major (nodes, N) one, in either
+    direction. Inside the loop slots lie on the last axis — the TPU's
+    lanes — so the schedule pass's tiles, ``assign`` and the per-job
+    column writes agree on one layout (DESIGN.md §7); the loop pays
+    this transpose once on entry and once on exit."""
+    return st._replace(assign=st.assign.T)
+
+
 def make_tick(cfg: SimConfig, jobs: Jobs, n_nodes: int,
               s=None, P=None, time_mode: str = None,
               max_ticks: int = 1 << 22, trace: bool = False):
@@ -1407,8 +1453,8 @@ def make_tick(cfg: SimConfig, jobs: Jobs, n_nodes: int,
                       max_ticks=max_ticks, trace=trace)
 
     def tick_step(st: State) -> State:
-        st, _ = step((st, _cache_from_state(jobs, st)))
-        return st
+        st, _ = step((_swap_assign(st), _cache_from_state(jobs, st)))
+        return _swap_assign(st)
 
     return tick_step
 
@@ -1472,9 +1518,9 @@ def _run_loop(cfg: SimConfig, jobs: Jobs, st: State, max_ticks: int,
         st, cache = step(carry)
         return st._replace(n_iter=st.n_iter + 1), cache
 
-    st, _ = jax.lax.while_loop(
-        cond, body, (st, _cache_from_state(jobs, st, round_end)))
-    return st
+    cache = _cache_from_state(jobs, st, round_end)
+    st, _ = jax.lax.while_loop(cond, body, (_swap_assign(st), cache))
+    return _swap_assign(st)
 
 
 def _run_jit_impl(cfg: SimConfig, jobs: Jobs, seed, time_mode: str,

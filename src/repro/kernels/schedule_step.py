@@ -212,7 +212,9 @@ def schedule_step_pallas(demand, gp, width, queue_key, assign, free,
     :func:`schedule_step_jnp`; grid = (2 phases, J/block_j job blocks)).
 
     The kernel works on (nodes, jobs) tiles, so the (J, M) ``assign``
-    goes in transposed and ``fits`` comes out transposed; the Eq. 3
+    goes in transposed and ``fits`` comes out transposed (the engine
+    loop holds ``assign`` node-major and passes its transpose, so the
+    two cancel in its compiled program); the Eq. 3
     scores are computed in XLA by the twin's own :func:`eq3_scores`.
     Scalars live in SMEM: the TE demand, the reduction state and the
     four scalar outputs."""

@@ -16,6 +16,7 @@ could not be read back without a chip).
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,3 +109,65 @@ def test_sim_loop_pallas_compiles(one_chip, native_kernels):
     compiled = sim_jax._run_jit_full.lower(
         cfg, jobs, sds((), jnp.int32), "event", False, 0).compile()
     assert _has_kernel(compiled)
+
+
+def _layout_copies(hlo: str, J: int) -> list:
+    """The ``copy`` ops of an optimised HLO text that change the layout
+    of a slots x nodes tile or of the slots x 3 demand: what the
+    compiler inserts where two ops disagree on which axis lies on the
+    lanes. Copies that keep the layout (a move between memory spaces,
+    a copy before an in-place update) are not counted."""
+    op = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]"
+                    r"\{([\d,]*)")
+    layout = {}
+    for line in hlo.splitlines():
+        m = op.match(line)
+        if m:
+            layout[m.group(1)] = m.group(4)
+    tiles = (sorted((J, M)), sorted((J, 3)))
+    found = []
+    for line in hlo.splitlines():
+        m = op.match(line)
+        if not m or " copy(%" not in line:
+            continue
+        dims = sorted(int(d) for d in m.group(3).split(",") if d)
+        src = re.search(r" copy\(%([\w.\-]+)\)", line).group(1)
+        if dims in tiles and layout.get(src) != m.group(4):
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("program", ["replay", "stream-round"])
+def test_loop_keeps_one_tile_layout(one_chip, program):
+    """The engine loop holds its slots x nodes tiles in one layout
+    (DESIGN.md §7): the compiled replay and stream-round programs of
+    the benchmark's configuration (fitgpp, s = 4, P = 1, jnp pass,
+    event time) transpose no such tile, nor the demand, inside the
+    loop. At most the loop's entry and exit transposes of ``assign``
+    may show as copies. Slot-major tiles gave 19 and 20 such copies
+    here, at 512 slots as at the cells' sizes."""
+    J = 512
+    cfg = api.make_config("fitgpp", n_jobs=J, n_nodes=M, s=4.0, P=1,
+                          score_backend="jnp")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    akey = sds((J,), jnp.float32) if program == "stream-round" else None
+    jobs = sim_jax.Jobs(
+        submit=sds((J,), jnp.int32), exec_total=sds((J,), jnp.int32),
+        demand=sds((J, 3), jnp.float32), is_te=sds((J,), jnp.bool_),
+        gp=sds((J,), jnp.int32), width=sds((J,), jnp.int32),
+        valid=sds((J,), jnp.bool_), akey=akey)
+    if program == "replay":
+        lowered = sim_jax._run_jit_full.lower(
+            cfg, jobs, sds((), jnp.int32), "event", False, 0)
+    else:
+        st = jax.eval_shape(
+            lambda jb: sim_jax.init_state(jb, M, cfg.cluster.node.as_tuple(),
+                                          0), jobs)
+        st = jax.tree.map(lambda x: sds(x.shape, x.dtype), st)
+        lowered = sim_jax._run_round_jit.lower(
+            cfg, jobs, st, sds((), jnp.int32), "event", False)
+    copies = _layout_copies(lowered.compile().as_text(), J)
+    assert len(copies) <= 2, "\n".join(copies)
