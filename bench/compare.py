@@ -12,19 +12,23 @@ job. The program and the reference draw from different generators, so
 the reference replays the program's draw (``reference.simulate`` with a
 ``Guide``): a running BE job the program preempted more often than the
 reference has so far, those the program signalled at this tick first
-where its outputs name each job's last preemption tick. A choice whose replay finishes a job
-at a tick the program does not have is dropped and the next tried. A
-unit whose draws were all replayed is compared whole, and the program
-must have drawn as often as the reference. A draw no choice explains is
-a mismatch; the unit is then compared over the jobs finished by that
-tick (both sides are the same deterministic process up to there), every
-job must still finish, and the program must have drawn too.
+where its outputs name each job's last preemption tick. A choice whose
+replay finishes a job at a tick the program does not have is dropped
+and the next tried. A unit whose draws were all replayed is compared
+whole, and the program must count as many fallbacks as the reference:
+random draws, and gang victims already at the cap ``P`` (rule 6 of
+``bench/reference.py``). A draw no choice explains is a mismatch; the
+unit is then compared over the jobs finished by that tick (both sides
+are the same deterministic process up to there), every job must still
+finish, and the program must have counted a fallback too.
 
 The control of the contract is, for a system that states no precision,
 the reference with one guarantee of the configuration broken
 (:func:`control`).
 """
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 
@@ -37,7 +41,8 @@ LIMITS = {"unfinished_jobs": 0, "finish_mismatch": 0,
 def tally(finish, preempt_count, draws: int,
           ref: reference.Result) -> dict:
     """Counts of one unit's jobs against the reference; ``draws`` is the
-    program's count of random victim draws in that unit."""
+    program's count of fallbacks in that unit (random victim draws, and
+    gang victims at the P cap)."""
     n = len(ref.finish)
     finish = np.asarray(-1 if finish is None else finish, np.int64)
     pc = np.asarray(-1 if preempt_count is None else preempt_count,
@@ -90,10 +95,7 @@ def control(jobs: reference.Jobs, cluster: dict, policy: dict, seed: int,
     if broken == "p_cap":
         P += 1
     elif broken == "grace":
-        jobs = reference.Jobs(submit=jobs.submit,
-                              exec_total=jobs.exec_total,
-                              demand=jobs.demand, is_te=jobs.is_te,
-                              gp=np.zeros_like(jobs.gp))
+        jobs = dataclasses.replace(jobs, gp=np.zeros_like(jobs.gp))
     else:
         raise ValueError(f"unknown control {broken!r}")
     return reference.simulate(jobs, cluster, policy["name"],

@@ -6,8 +6,9 @@
 For each seed: the cell's job sets, and the reference with one guarantee
 of the configuration broken (``compare.control``: ``p_cap``, ``grace``)
 in the program's place, compared with the plain reference as a run's
-outputs are. Prints one JSON line per seed and control with each number
-compared. Numpy only; the benchmark's runs never call it.
+outputs are. Gang jobs keep their widths in the control. Prints one
+JSON line per seed and control with each number compared. Numpy only;
+the benchmark's runs never call it.
 """
 from __future__ import annotations
 

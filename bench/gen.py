@@ -4,7 +4,10 @@ traffic file's sizes and arrivals, and ``--seed``.
 Jobs are drawn per class (TE or BE, ``te_share``) from truncated normals
 (resampled a few times, then clipped), GPU requests snapped to the
 allocation quanta, grace periods from their own truncated normal, as in
-the paper's §4.2 generator. Arrivals:
+the paper's §4.2 generator. Gang (multi-node) jobs, the paper's future
+work: a job is a gang with the mix's ``gang_share``, of a width drawn
+uniformly from the mix's ``gang_widths``. Its demand stays per node.
+Arrivals:
 
 * ``closed_loop``: the paper's "load kept at ``load`` if scheduled by
   FIFO": a FIFO replay of the jobs (``reference.Simulator`` with an
@@ -13,7 +16,10 @@ the paper's §4.2 generator. Arrivals:
 
 A job set is a ``reference.Jobs``, drawn from
 ``numpy.random.default_rng((seed, 0))``, so a seed gives the same jobs in
-every run.
+every run. Widths come from a stream of their own,
+``numpy.random.default_rng((seed, 1))``, drawn only where a share is
+above 0: a mix without gangs draws exactly the jobs it drew before gangs
+were added.
 """
 from __future__ import annotations
 
@@ -46,9 +52,8 @@ def _class(rng, cls: dict, n: int, quanta) -> tuple:
 
 
 def draw(mix: dict, n: int, rng) -> reference.Jobs:
-    """``n`` jobs of the configuration's ``jobs`` mix, unsubmitted."""
-    if mix.get("gang_share", 0.0):
-        raise ValueError("the generator draws single-node jobs only")
+    """``n`` jobs of the configuration's ``jobs`` mix, unsubmitted, of
+    width 1."""
     is_te = rng.random(n) < mix["te_share"]
     exec_total = np.zeros(n, np.int64)
     demand = np.zeros((n, 3))
@@ -63,9 +68,28 @@ def draw(mix: dict, n: int, rng) -> reference.Jobs:
                           gp=gp)
 
 
+def widths(mix: dict, n: int, nodes: int, seed: int) -> np.ndarray:
+    """Each of ``n`` jobs' width: gangs by ``gang_share``, widths
+    uniform over ``gang_widths``; all ones, and no draw, where the share
+    is 0."""
+    share = float(mix.get("gang_share", 0.0))
+    width = np.ones(n, np.int64)
+    if share <= 0:
+        return width
+    choices = np.asarray(mix["gang_widths"], np.int64)
+    if not ((choices >= 1) & (choices <= nodes)).all():
+        raise ValueError(f"gang widths {choices.tolist()} must lie in "
+                         f"1..{nodes}, the cluster's nodes")
+    rng = np.random.default_rng((int(seed), 1))
+    gang = rng.random(n) < share
+    width[gang] = rng.choice(choices, int(gang.sum()))
+    return width
+
+
 def closed_loop(jobs: reference.Jobs, cluster: dict,
                 load: float) -> np.ndarray:
-    """Admit ticks of a FIFO replay that holds the backlog at ``load``."""
+    """Admit ticks of a FIFO replay that holds the backlog at ``load``
+    (a job weighs its width times its cluster-normalised demand)."""
     sim = reference.Simulator(jobs, cluster["nodes"],
                               reference.node_cap(cluster), "fifo", 0.0, 0,
                               seed=0, admission_target=load)
@@ -82,6 +106,7 @@ def build(config: dict, traffic: dict, seed: int) -> reference.Jobs:
         raise ValueError(f"unknown arrivals {arrivals['kind']!r}")
     rng = np.random.default_rng((int(seed), 0))
     js = draw(config["jobs"], int(traffic["jobs"]), rng)
+    js.width = widths(config["jobs"], js.n, config["cluster"]["nodes"], seed)
     js.submit = closed_loop(js, config["cluster"], arrivals["load"])
     cap = np.asarray(reference.node_cap(config["cluster"]))
     if not (js.demand <= cap[None, :]).all():

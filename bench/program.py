@@ -35,4 +35,4 @@ def jobset(js):
                   demand=np.asarray(js.demand, np.float64),
                   is_te=np.asarray(js.is_te, bool),
                   gp=np.asarray(js.gp, np.int64),
-                  n_nodes=np.ones(js.n, np.int64))
+                  n_nodes=np.asarray(js.width, np.int64))

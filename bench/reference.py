@@ -1,17 +1,52 @@
 """Plain numpy reference of the scheduling semantics the benchmark holds
 the program to. It imports nothing of the program.
 
-A straightforward discrete-time simulator of arXiv:1902.01613 §4.1, for
-single-node jobs under two policies:
+A straightforward discrete-time simulator of arXiv:1902.01613 §4.1, with
+the multi-node (gang) jobs the paper leaves to future work, under two
+policies:
 
 * ``fifo``: one queue, strict head-of-line order, no preemption;
 * ``fitgpp``: TE jobs in a priority lane served first; a TE job that
-  does not fit even counting resources promised by grace periods in
-  flight picks one victim among running BE jobs by Eq. 1-4 (smallest
-  Eq. 3 score among the Eq. 2-eligible jobs preempted fewer than ``P``
-  times; a random running BE job when none is eligible). A victim keeps
-  its resources for its grace period, then re-enters the BE lane at the
-  top.
+  does not fit may signal victims among running BE jobs (below). A
+  victim keeps its resources for its grace period, then re-enters the
+  BE lane at the top.
+
+The rules, each as ``core/engine/`` of the program specifies it:
+
+1. Placement. A job of width ``w`` needs its demand, which is per node,
+   on ``w`` distinct nodes at once: it starts on the first ``w`` nodes
+   that fit it, in ascending node index, or not at all. ``w`` = 1 is
+   first-fit. A node fits when its free vector is at least the demand
+   less ``FIT_EPS`` in every resource.
+2. One node list per job, from its start to its finish or vacate. A
+   finish or a vacate releases the demand on every node of the list; a
+   preemption signal promises it (``pending``) on every node of the
+   list until the vacate.
+3. Trigger. A TE job that does not fit signals victims only if no
+   victim it signalled is still in grace and fewer than ``w`` nodes fit
+   it even counting promised resources (``free + pending``).
+4. Victims of a width-1 TE job (Eq. 1-4): the smallest Eq. 3 score
+   (the Eq. 1 size of the per-node demand, normalised by the largest
+   over all running BE jobs, plus ``s`` times the grace period so
+   normalised) among the running BE jobs preempted fewer than ``P``
+   times that are eligible by Eq. 2 (TE demand at most the victim's
+   demand plus the free vector of its node, within ``FIT_EPS``); ties by
+   job index. A gang victim is judged on its node with the most slack:
+   the largest smallest ``free + demand - TE demand`` over the
+   resources, the first such node in its list. None eligible: one
+   running BE job at random (Eq. 4's fallback).
+5. Victims of a gang TE job (width ``w``), with no random draw: every
+   running BE job is ranked, under the ``P`` cap first, then by the
+   Eq. 3 score of its total demand ``width × demand`` (normalised over
+   these totals), ties by job index. First choice: the first single
+   job in that order, among those under the cap if there are any,
+   whose demand given back to ``free`` on its nodes alone leaves ``w``
+   nodes that fit. Otherwise jobs are taken in rank order, over the cap
+   too, until ``w`` nodes fit on ``free`` plus what they give back; if
+   even all of them are not enough, none is taken. Victims are
+   signalled in the order taken.
+6. Fallbacks, as the program counts them: each random draw of rule 4,
+   and each victim of rule 5 already preempted ``P`` times.
 
 The random draws are the one place two correct engines part. Given
 another engine's outputs for the same jobs (a :class:`Guide`),
@@ -24,10 +59,10 @@ grace countdown. Ticks on which nothing can start, preempt, arrive,
 finish or expire are skipped in one jump, which changes no result.
 
 With ``admission_target > 0`` submit times are ignored: the next job (in
-index order) is admitted whenever the backlog, the cluster-normalised
-demand of admitted unfinished jobs, is below the target. Run under
-``fifo`` this gives the paper's closed-loop arrivals (§4.2), and
-``admit_time`` holds the ticks.
+index order) is admitted whenever the backlog, the sum over admitted
+unfinished jobs of the mean cluster-normalised demand times the width,
+is below the target. Run under ``fifo`` this gives the paper's
+closed-loop arrivals (§4.2), and ``admit_time`` holds the ticks.
 """
 from __future__ import annotations
 
@@ -43,13 +78,19 @@ MAX_TICKS = 10_000_000
 
 @dataclass
 class Jobs:
-    """Struct of arrays over ``n`` single-node jobs; demand per
-    (CPU, RAM GB, GPU), times in minutes."""
+    """Struct of arrays over ``n`` jobs; demand per node per (CPU, RAM GB,
+    GPU), times in minutes; ``width`` the nodes a job needs at once (all
+    ones by default)."""
     submit: np.ndarray
     exec_total: np.ndarray
     demand: np.ndarray
     is_te: np.ndarray
     gp: np.ndarray
+    width: np.ndarray = None
+
+    def __post_init__(self):
+        if self.width is None:
+            self.width = np.ones(len(self.submit), np.int64)
 
     @property
     def n(self) -> int:
@@ -77,7 +118,10 @@ class Result:
     preempt_count: np.ndarray
     makespan: int
     last_signal: np.ndarray     # tick of each job's last preemption
-    fallbacks: int              # random victim draws (Eq. 4's fallback)
+    # victims past the main rule, as the program counts them: random
+    # draws (Eq. 4's fallback) and gang victims already at the P cap
+    fallbacks: int
+    draws: int                  # random victim draws alone
     first_draw: int             # tick of the first draw not replayed, or -1
     missed: int                 # draws no choice consistent with the guide
     admit_time: np.ndarray
@@ -150,10 +194,11 @@ class Simulator:
         self.pending = np.zeros_like(self.free)
         n = jobs.n
         self.demand = np.asarray(jobs.demand, np.float64)
+        self.width = np.asarray(jobs.width, np.int64)
         self.remaining = np.asarray(jobs.exec_total, np.int64).copy()
         self.finish_t = np.full(n, -1, np.int64)
         self.state = np.full(n, NOT_ARRIVED, np.int8)
-        self.node = np.full(n, -1, np.int64)
+        self.nodes = {}             # running or in-grace job -> its nodes
         self.preempt_count = np.zeros(n, np.int64)
         self.grace_left = np.zeros(n, np.int64)
         self.victim_of = np.full(n, -1, np.int64)
@@ -167,6 +212,7 @@ class Simulator:
         self.options = {}           # draw index -> jobs it could have been
         self.speculative = []       # draw indices chosen among several
         self.fallbacks = 0
+        self.draws = 0
         self.first_draw = -1
         self.missed = 0
         self.lanes = _Lanes(self.state)
@@ -174,21 +220,23 @@ class Simulator:
         self.backlog = 0.0
         self.admit_time = np.full(n, -1, np.int64)
         self.frac = (self.demand / (self.node_cap * int(n_nodes))[None, :]
-                     ).mean(axis=1)
+                     ).mean(axis=1) * self.width
         self.order = np.argsort(jobs.submit, kind="stable")
         self.next = 0
 
     # -- placement ----------------------------------------------------------
 
     def _fit(self, j):
+        """The first ``width`` nodes that fit job ``j``, or None."""
         ok = np.all(self.free >= self.demand[j][None, :] - FIT_EPS, axis=1)
         idx = np.flatnonzero(ok)
-        return int(idx[0]) if len(idx) else -1
+        w = self.width[j]
+        return idx[:w] if len(idx) >= w else None
 
     def _fits_with_pending(self, j):
         promised = self.free + self.pending
-        return bool(np.all(promised >= self.demand[j][None, :] - FIT_EPS,
-                           axis=1).any())
+        ok = np.all(promised >= self.demand[j][None, :] - FIT_EPS, axis=1)
+        return int(ok.sum()) >= self.width[j]
 
     def _te_lane(self, j):
         return self.preemptive and bool(self.jobs.is_te[j])
@@ -199,10 +247,10 @@ class Simulator:
         self.state[j] = QUEUED
         self.lanes.push_back(j, self._te_lane(j))
 
-    def _start(self, j, node):
+    def _start(self, j, nodes):
         self.state[j] = RUNNING
-        self.node[j] = node
-        self.free[node] -= self.demand[j]
+        self.nodes[j] = nodes
+        self.free[nodes] -= self.demand[j]
         self.running.add(j)
         if not self.jobs.is_te[j]:
             self.running_be.add(j)
@@ -223,17 +271,16 @@ class Simulator:
         self.te_pending[te] += 1
         self.running.discard(v)
         self.running_be.discard(v)
-        self.pending[self.node[v]] += self.demand[v]
+        self.pending[self.nodes[v]] += self.demand[v]
         if gp <= 0:
             self._vacate(v)
         else:
             self.grace.add(v)
 
     def _vacate(self, v):
-        node = self.node[v]
-        self.free[node] += self.demand[v]
-        self.pending[node] -= self.demand[v]
-        self.node[v] = -1
+        nodes = self.nodes.pop(v)
+        self.free[nodes] += self.demand[v]
+        self.pending[nodes] -= self.demand[v]
         self.state[v] = QUEUED
         self.grace.discard(v)
         self.lanes.requeue_top(v, self._te_lane(v))
@@ -243,8 +290,7 @@ class Simulator:
             self.victim_of[v] = -1
 
     def _finish(self, j, t):
-        self.free[self.node[j]] += self.demand[j]
-        self.node[j] = -1
+        self.free[self.nodes.pop(j)] += self.demand[j]
         self.state[j] = DONE
         self.running.discard(j)
         self.running_be.discard(j)
@@ -256,27 +302,84 @@ class Simulator:
 
     # -- Eq. 1-4 ------------------------------------------------------------
 
-    def _pick_victim(self, te, t):
+    def _candidates(self):
+        """Running BE jobs in index order and their grace periods."""
         cand = np.sort(np.fromiter(self.running_be, np.int64,
                                    count=len(self.running_be)))
-        d = self.demand[cand]
         gp = np.asarray(self.jobs.gp[cand], np.float64)
+        return cand, gp
+
+    def _score(self, d, gp):
+        """Eq. 3 over the candidates, of their demand ``d``."""
         size = np.sqrt(np.sum((d / self.node_cap) ** 2, axis=-1))   # Eq. 1
-        score = (size / max(size.max(initial=0.0), 1e-12)           # Eq. 3
-                 + self.s * (gp / max(gp.max(initial=0), 1e-12)))
-        node_free = self.free[self.node[cand]]
+        return (size / max(size.max(initial=0.0), 1e-12)            # Eq. 3
+                + self.s * (gp / max(gp.max(initial=0), 1e-12)))
+
+    def _victims(self, te, t):
+        if self.width[te] > 1:
+            return self._gang_victims(te)
+        return [self._pick_victim(te, t)]
+
+    def _best_node(self, v, te):
+        """The node of gang victim ``v`` with the most slack for ``te``."""
+        nodes = self.nodes[v]
+        slack = np.min(self.free[nodes] + self.demand[v][None, :]
+                       - self.demand[te][None, :], axis=1)
+        return nodes[int(np.argmax(slack))]
+
+    def _pick_victim(self, te, t):
+        cand, gp = self._candidates()
+        d = self.demand[cand]
+        score = self._score(d, gp)
+        node = np.fromiter((self.nodes[c][0] if self.width[c] == 1
+                            else self._best_node(c, te) for c in cand),
+                           np.int64, count=len(cand))
+        node_free = self.free[node]
         elig = np.all(self.demand[te][None, :] <= d + node_free + FIT_EPS,
                       axis=1)                                        # Eq. 2
         ok = elig & (self.preempt_count[cand] < self.P)
         if ok.any():                                                 # Eq. 4
             return int(cand[int(np.argmin(np.where(ok, score, np.inf)))])
-        k = self.fallbacks
+        k = self.draws
+        self.draws += 1
         self.fallbacks += 1
         if self.guide is not None:
             return self._replay_draw(k, cand, t)
         if self.first_draw < 0:
             self.first_draw = t
         return int(cand[int(self.rng.integers(len(cand)))])
+
+    def _gang_victims(self, te):
+        """Rule 5 of the module docstring: the victims of gang TE job
+        ``te``, in the order they are signalled."""
+        cand, gp = self._candidates()
+        w = self.width[te]
+        need = self.demand[te][None, :] - FIT_EPS
+
+        def n_fit(free):
+            return int(np.all(free >= need, axis=1).sum())
+
+        score = self._score(self.demand[cand] * self.width[cand][:, None], gp)
+        under = self.preempt_count[cand] < self.P
+        order = np.lexsort((score, ~under))
+        pool = order[under[order]] if under.any() else order
+        for i in pool:
+            trial = self.free.copy()
+            trial[self.nodes[int(cand[i])]] += self.demand[cand[i]]
+            if n_fit(trial) >= w:
+                self.fallbacks += int(not under[i])
+                return [int(cand[i])]
+        given = self.free.copy()
+        victims = []
+        for i in order:
+            if n_fit(given) >= w:
+                break
+            given[self.nodes[int(cand[i])]] += self.demand[cand[i]]
+            victims.append(int(cand[i]))
+        if n_fit(given) < w:
+            return []
+        self.fallbacks += int((self.preempt_count[victims] >= self.P).sum())
+        return victims
 
     def _replay_draw(self, k, cand, t):
         """The guide's draw: a running BE job it preempted more often
@@ -313,13 +416,14 @@ class Simulator:
                 j = self.lanes.pop(True)
                 if j < 0:
                     break
-                node = self._fit(j)
-                if node < 0 and self._should_trigger(j):
+                nodes = self._fit(j)
+                if nodes is None and self._should_trigger(j):
                     if self.running_be:
-                        self._signal(self._pick_victim(j, t), j, t)
-                    node = self._fit(j)
-                if node >= 0:
-                    self._start(j, node)
+                        for v in self._victims(j, t):
+                            self._signal(v, j, t)
+                    nodes = self._fit(j)
+                if nodes is not None:
+                    self._start(j, nodes)
                 else:
                     blocked.append(j)
             for j in blocked:
@@ -328,21 +432,21 @@ class Simulator:
             head = self.lanes.peek(False)
             if head < 0:
                 break
-            node = self._fit(head)
-            if node < 0:
+            nodes = self._fit(head)
+            if nodes is None:
                 break
             self.lanes.pop(False)
-            self._start(head, node)
+            self._start(head, nodes)
 
     def _would_act(self):
         if self.preemptive:
             for j in self.lanes.queued(True):
-                if self._fit(j) >= 0:
+                if self._fit(j) is not None:
                     return True
                 if self.running_be and self._should_trigger(j):
                     return True
         head = self.lanes.peek(False)
-        return head >= 0 and self._fit(head) >= 0
+        return head >= 0 and self._fit(head) is not None
 
     # -- time ---------------------------------------------------------------
 
@@ -420,7 +524,8 @@ class Simulator:
         return Result(finish=self.finish_t.copy(),
                       preempt_count=self.preempt_count.copy(), makespan=t,
                       last_signal=self.last_signal.copy(),
-                      fallbacks=self.fallbacks, first_draw=self.first_draw,
+                      fallbacks=self.fallbacks, draws=self.draws,
+                      first_draw=self.first_draw,
                       missed=self.missed,
                       admit_time=self.admit_time.copy())
 

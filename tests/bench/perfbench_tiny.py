@@ -2,9 +2,12 @@
 directory made in a temporary directory, and a run through
 ``bench.run.execute`` (everything of a run after the look for a chip).
 
-The sizes and seeds take no random victim draw, so every job is
-compared exactly: 24 nodes, 384 jobs (seed 5) for the replay, 1,536
-jobs through the 768-slot pool (seed 9) for the stream."""
+The sizes and seeds of the single-node cells take no random victim
+draw, so every job is compared exactly: 24 nodes, 384 jobs (seed 5) for
+the replay, 1,536 jobs through the 768-slot pool (seed 9) for the
+stream. A ``gang-`` cell runs the same path on a gang mix (``MIXES``);
+the gang stream's seed takes random draws, which the comparison replays
+from the program's outputs."""
 import json
 import os
 import sys
@@ -24,35 +27,48 @@ TRAFFIC = {
     "stream": {"path": "stream", "jobs": 1536, "chunk": 256,
                "arrivals": LOAD},
 }
-SEED = {"replay": 5, "stream": 9}
+SEED = {"replay": 5, "stream": 9, "gang-replay": 3, "gang-stream": 9}
 E2E = {"replay": ["replay_jobs_per_s"],
        "stream": ["stream_jobs_per_s", "round_ms_p95"]}
+# gang mixes over paper-84n's jobs: half of them gangs of 2, 3 or 4
+# nodes, or a quarter gangs of 6 or 12 (up to half the 24 nodes)
+MIXES = {"gangs": {"gang_share": 0.5, "gang_widths": [2, 3, 4]},
+         "wide-gangs": {"gang_share": 0.25, "gang_widths": [6, 12]}}
+
+
+def config(mix: str = None, nodes: int = 24) -> dict:
+    """paper-84n cut to ``nodes``, with the gang mix ``mix`` if given."""
+    cfg = run.load_json("configs", "paper-84n")
+    jobs = dict(cfg["jobs"], **MIXES[mix]) if mix else cfg["jobs"]
+    return dict(cfg, name=f"tiny-{nodes}n" + (f"-{mix}" if mix else ""),
+                cluster=dict(cfg["cluster"], nodes=nodes), jobs=jobs)
 
 
 def cell(tmp_path, kind: str, per_layer=()):
     """(manifest, cell) of a tiny ``kind`` cell named ``tiny-<kind>``,
-    which reports the per-layer metrics of its path (``*.<kind>``) and
-    those named in ``per_layer``."""
+    which reports the per-layer metrics of its path (``*.<path>``) and
+    those named in ``per_layer``. ``gang-<path>`` runs ``<path>`` on the
+    ``gangs`` mix."""
     d = str(tmp_path)
     for k in ("cells", "configs", "traffic"):
         os.makedirs(os.path.join(d, k), exist_ok=True)
-    cfg = run.load_json("configs", "paper-84n")
-    cfg.update(name="tiny-24n", cluster=dict(cfg["cluster"], nodes=24))
+    path = kind.removeprefix("gang-")
+    cfg = config("gangs" if path != kind else None)
     name = f"tiny-{kind}"
-    files = {"configs/tiny-24n": cfg, f"traffic/{name}": TRAFFIC[kind],
-             f"cells/{name}": {"config": "tiny-24n", "traffic": name,
+    files = {f"configs/{cfg['name']}": cfg, f"traffic/{name}": TRAFFIC[path],
+             f"cells/{name}": {"config": cfg["name"], "traffic": name,
                                "chips": 1, "why": "a CPU rehearsal"}}
     for rel, body in files.items():
         with open(os.path.join(d, rel + ".json"), "w") as f:
             json.dump(body, f)
     man = run.manifest()
-    man["workloads"].append({"name": name, "config": "tiny-24n",
+    man["workloads"].append({"name": name, "config": cfg["name"],
                              "traffic": name, "chips": 1, "why": "x"})
     for m in man["end_to_end"]:
-        if m["name"] in E2E[kind]:
+        if m["name"] in E2E[path]:
             m["workloads"].append(name)
     for m in man["per_layer"]:
-        if m["name"].endswith("." + kind) or m["name"] in per_layer:
+        if m["name"].endswith("." + path) or m["name"] in per_layer:
             m["workloads"].append(name)
     return man, run.load_cell(name, (d, run.BENCH))
 

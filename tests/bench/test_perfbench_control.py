@@ -3,8 +3,10 @@ control at a size a test run holds.
 
 The control is the reference with one guarantee of the configuration
 broken, in the program's place; the comparison must find it incorrect
-on every seed. The reference imports nothing of the program; here it is
-checked against the program's own numpy engine, which it mirrors."""
+on every seed, on single-node jobs and on gang mixes
+(``perfbench_tiny.MIXES``). The reference imports nothing of the
+program; here it is checked against the program's own numpy engine,
+which it mirrors, job for job."""
 import dataclasses
 import os
 import sys
@@ -15,16 +17,18 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import perfbench_tiny as tiny  # noqa: E402
 from bench import compare, gen, reference, run  # noqa: E402
 
 SEEDS = (5, 7, 9)          # no random victim draw at this size
+GANG_SEEDS = (3, 4, 9)     # nor on the gangs mix
 
 
 @pytest.fixture(scope="module")
 def config():
-    cfg = run.load_json("configs", "paper-84n")
-    return dict(cfg, cluster=dict(cfg["cluster"], nodes=24))
+    return tiny.config()
 
 
 def _jobs(config, seed, n=384):
@@ -32,15 +36,21 @@ def _jobs(config, seed, n=384):
         "kind": "closed_loop", "load": 2.0}}, seed)
 
 
-@pytest.mark.parametrize("seed", SEEDS)
-@pytest.mark.parametrize("broken", ["p_cap", "grace"])
-def test_control_is_incorrect(config, seed, broken):
+@pytest.mark.parametrize("mix,broken,seed", [
+    pytest.param(None, b, s, id=f"{b}-{s}") for b in ("p_cap", "grace")
+    for s in SEEDS] + [
+    pytest.param("gangs", b, s, id=f"gangs-{b}-{s}")
+    for b in ("p_cap", "grace") for s in GANG_SEEDS])
+def test_control_is_incorrect(config, mix, broken, seed):
+    config = tiny.config(mix) if mix else config
     js = _jobs(config, seed)
     pol = config["policy"]
     ref = reference.simulate(js, config["cluster"], "fitgpp", pol["s"],
                              pol["P"], seed)
-    assert ref.fallbacks == 0
-    sound = compare.tally(ref.finish, ref.preempt_count, 0, ref)
+    assert ref.draws == 0
+    assert (js.width > 1).any() == bool(mix)
+    sound = compare.tally(ref.finish, ref.preempt_count, ref.fallbacks,
+                          ref)
     assert compare.passed(compare.checks(compare.total([sound])))
     ctl = compare.control(js, config["cluster"], pol, seed, broken)
     t = compare.tally(ctl.finish, ctl.preempt_count, ctl.fallbacks, ref)
@@ -84,14 +94,24 @@ def test_unreplayable_draws_are_a_mismatch(config):
         compare.checks(compare.total([t])))
 
 
-@pytest.mark.parametrize("seed", SEEDS)
-def test_reference_matches_the_programs_numpy_engine(config, seed):
+@pytest.mark.parametrize("mix,seed", [
+    pytest.param(None, s, id=str(s)) for s in SEEDS] + [
+    pytest.param(m, s, id=f"{m}-{s}") for m in tiny.MIXES for s in SEEDS])
+def test_reference_matches_the_programs_numpy_engine(config, mix, seed):
+    """Job for job: submit ticks of the closed loop, finish ticks and
+    preemption counts. The gang mixes take gang TE victims both ways
+    (one victim, and several), gang victims of width-1 TE jobs on their
+    best node, and random draws, which the reference's generator draws
+    as the program's does."""
     from repro.configs.cluster import ClusterSpec, SimConfig
     from repro.core import simulator, workload
     from repro.core.types import JobSet
+    config = tiny.config(mix) if mix else config
     js = _jobs(config, seed)
+    assert (js.width > 1).any() == bool(mix)
     data = JobSet(submit=np.zeros(js.n, np.int64), exec_total=js.exec_total,
-                  demand=js.demand, is_te=js.is_te, gp=js.gp)
+                  demand=js.demand, is_te=js.is_te, gp=js.gp,
+                  n_nodes=js.width)
     cfg = SimConfig(cluster=ClusterSpec(n_nodes=24), seed=seed)
     admit = workload.closed_loop_submit_times(cfg, data)
     assert np.array_equal(admit, js.submit)
@@ -111,10 +131,33 @@ def test_generator_is_seeded(config):
     assert (a.is_te.mean() > 0.1) and (a.gp <= 20).all()
 
 
+def test_generator_draws_gangs(config):
+    """Widths come from a stream of their own: a gang mix draws the same
+    jobs as its mix without gangs, and only its widths (and so its
+    admit ticks) differ."""
+    base = _jobs(config, 11)
+    gangs = _jobs(tiny.config("gangs"), 11)
+    wide = _jobs(tiny.config("wide-gangs"), 11)
+    for js in (gangs, wide):
+        for f in ("exec_total", "demand", "is_te", "gp"):
+            assert np.array_equal(getattr(js, f), getattr(base, f))
+        assert not np.array_equal(js.submit, base.submit)
+    assert set(np.unique(gangs.width)) == {1, 2, 3, 4}
+    assert 0.4 < (gangs.width > 1).mean() < 0.6
+    assert set(np.unique(wide.width)) == {1, 6, 12}
+    assert 0.15 < (wide.width > 1).mean() < 0.35
+    wide = tiny.config("gangs", nodes=3)
+    with pytest.raises(ValueError, match="gang widths"):
+        _jobs(wide, 11)
+
+
 def test_control_script(config, capsys):
     from bench import control
     c = run.load_cell("paper84-replay")
     c.traffic = dict(c.traffic, jobs=384)
     c.config = config
     r = control.readings(c, 5, "p_cap")
+    assert r["finish_mismatch"] > 0 and r["compared"] == 384
+    c.config = tiny.config("gangs")
+    r = control.readings(c, 3, "grace")
     assert r["finish_mismatch"] > 0 and r["compared"] == 384

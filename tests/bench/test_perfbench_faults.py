@@ -3,12 +3,14 @@ the harness as a chip run does (the look for a chip skipped), with one
 fault planted in the program, and ``correct`` must come out false.
 
 Faults, on each path: a step that returns its state unchanged; an
-answer altered where it is produced; half of the batch left out. No
-cell runs across chips, so none can leave out an exchange between
-them."""
+answer altered where it is produced; half of the batch left out. The
+last two also on a gang mix (``gang-<path>``). No cell runs across
+chips, so none can leave out an exchange between them."""
 import dataclasses
 import os
 import sys
+
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
@@ -31,7 +33,8 @@ def test_replay_state_unchanged(tmp_path, monkeypatch):
     _incorrect(tiny.execute(tmp_path, "replay"), "unfinished_jobs")
 
 
-def test_replay_answer_altered(tmp_path, monkeypatch):
+def _alter_replay(monkeypatch):
+    """One job's finish tick one later, as the replay returns it."""
     from repro import api
     real = api.run_experiment
 
@@ -41,6 +44,10 @@ def test_replay_answer_altered(tmp_path, monkeypatch):
         return dataclasses.replace(
             r, raw=(jobs, st._replace(finish=st.finish.at[17].add(1))))
     monkeypatch.setattr(api, "run_experiment", altered)
+
+
+def test_replay_answer_altered(tmp_path, monkeypatch):
+    _alter_replay(monkeypatch)
     _incorrect(tiny.execute(tmp_path, "replay"), "finish_mismatch")
 
 
@@ -60,7 +67,8 @@ def test_stream_state_unchanged(tmp_path, monkeypatch):
     _incorrect(tiny.execute(tmp_path, "stream"), "unfinished_jobs")
 
 
-def test_stream_answer_altered(tmp_path, monkeypatch):
+def _alter_stream(monkeypatch):
+    """One job's preemption count one higher, as the stream returns it."""
     from repro.core.stream import engine
     real = engine.StreamEngine._finalize
 
@@ -69,10 +77,14 @@ def test_stream_answer_altered(tmp_path, monkeypatch):
         res.preempt_count[100] += 1
         return res
     monkeypatch.setattr(engine.StreamEngine, "_finalize", altered)
+
+
+def test_stream_answer_altered(tmp_path, monkeypatch):
+    _alter_stream(monkeypatch)
     _incorrect(tiny.execute(tmp_path, "stream"), "preempt_mismatch")
 
 
-def test_replay_half_the_batch_left_out(tmp_path, monkeypatch):
+def _half_replay(monkeypatch):
     """The replay program is handed only the first half of the jobs."""
     import jax.numpy as jnp
 
@@ -84,11 +96,15 @@ def test_replay_half_the_batch_left_out(tmp_path, monkeypatch):
         n = jobs.valid.shape[0]
         return jobs._replace(valid=jobs.valid & (jnp.arange(n) < n // 2))
     monkeypatch.setattr(sim_jax, "jobs_from_jobset", half)
+
+
+def test_replay_half_the_batch_left_out(tmp_path, monkeypatch):
+    _half_replay(monkeypatch)
     out = tiny.execute(tmp_path, "replay")
     assert not out["correct"] and out["failed"] >= 384 // 2
 
 
-def test_stream_half_the_batch_left_out(tmp_path, monkeypatch):
+def _half_stream(monkeypatch):
     """The streaming engine packs only the first half of each batch of
     arrivals it takes from the source."""
     from repro.core.stream import JobSource
@@ -101,5 +117,28 @@ def test_stream_half_the_batch_left_out(tmp_path, monkeypatch):
         return type(js)(**{f: getattr(js, f)[:js.n // 2] for f in (
             "submit", "exec_total", "demand", "is_te", "gp", "n_nodes")})
     monkeypatch.setattr(JobSource, "take", half)
+
+
+def test_stream_half_the_batch_left_out(tmp_path, monkeypatch):
+    _half_stream(monkeypatch)
     out = tiny.execute(tmp_path, "stream")
+    assert not out["correct"] and out["failed"] > 0
+
+
+ALTER = {"replay": (_alter_replay, "finish_mismatch"),
+         "stream": (_alter_stream, "preempt_mismatch")}
+HALF = {"replay": _half_replay, "stream": _half_stream}
+
+
+@pytest.mark.parametrize("path", ["replay", "stream"])
+def test_gang_answer_altered(tmp_path, monkeypatch, path):
+    plant, check = ALTER[path]
+    plant(monkeypatch)
+    _incorrect(tiny.execute(tmp_path, "gang-" + path), check)
+
+
+@pytest.mark.parametrize("path", ["replay", "stream"])
+def test_gang_half_the_batch_left_out(tmp_path, monkeypatch, path):
+    HALF[path](monkeypatch)
+    out = tiny.execute(tmp_path, "gang-" + path)
     assert not out["correct"] and out["failed"] > 0

@@ -16,12 +16,14 @@ import perfbench_tiny as tiny  # noqa: E402
 ROOT = tiny.ROOT
 
 
-@pytest.mark.parametrize("kind", ["replay", "stream"])
+@pytest.mark.parametrize("kind", ["replay", "stream", "gang-replay",
+                                  "gang-stream"])
 def test_path_runs_and_is_correct(tmp_path, kind, capsys):
     out = tiny.execute(tmp_path, kind)
     assert out["correct"], out["checks"]
     assert out["failed"] == 0 and out["attempted"] > 0
-    assert set(out["metrics"]) == {"setup_s", *tiny.E2E[kind]}
+    path = kind.removeprefix("gang-")
+    assert set(out["metrics"]) == {"setup_s", *tiny.E2E[path]}
     assert all(m["value"] > 0 for m in out["metrics"].values())
     assert list(out)[-1] == "checks"
     assert all(c["value"] == 0 for c in out["checks"].values())
